@@ -45,6 +45,7 @@ same-bucket panes (tests/test_sharded_state.py pins it).
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -112,8 +113,6 @@ class _CachedFn:
         "compiles",
         "compile_time_s",
         "calls",
-        "_sig_fallback",
-        "_seen_sigs",
     )
 
     def __init__(self, fn: Callable, label: Any, jit_kwargs: dict, log_key: Any = None):
@@ -125,19 +124,6 @@ class _CachedFn:
         self.compiles = 0
         self.compile_time_s = 0.0
         self.calls = 0
-        # _cache_size is a private jax hook; when a build lacks it, fall
-        # back to tracking abstract signatures ourselves (slower per call,
-        # but the counters keep MEASURING instead of silently reporting 0
-        # compiles — the bench's zero-recompile guard must never pass
-        # vacuously)
-        self._sig_fallback = not callable(getattr(self._jit, "_cache_size", None))
-        self._seen_sigs = set() if self._sig_fallback else None
-
-    def _cache_size(self) -> int:
-        try:
-            return self._jit._cache_size()
-        except Exception:
-            return -1
 
     def _record_compile(self, n: int, dt: float, sig) -> None:
         global _COMPILES, _COMPILE_TIME_S
@@ -155,22 +141,12 @@ class _CachedFn:
     def __call__(self, *args, **kwargs):
         global _DISPATCH_HITS
         self.calls += 1
-        if self._sig_fallback:
-            sig = _abstract_sig(args, kwargs)
-            fresh = sig not in self._seen_sigs
-            t0 = time.perf_counter()
-            out = self._jit(*args, **kwargs)
-            if fresh:
-                self._seen_sigs.add(sig)
-                self._record_compile(1, time.perf_counter() - t0, sig)
-            else:
-                with _LOCK:
-                    _DISPATCH_HITS += 1
-            return out
-        before = self._cache_size()
+        # the jit's own signature cache (a private hook of the installed
+        # jax) grows exactly when this call traced and compiled
+        before = self._jit._cache_size()
         t0 = time.perf_counter()
         out = self._jit(*args, **kwargs)
-        after = self._cache_size()
+        after = self._jit._cache_size()
         if after > before:
             self._record_compile(
                 after - before,
@@ -299,3 +275,25 @@ def clear() -> None:
     with _LOCK:
         _ENTRIES.clear()
     reset_stats()
+
+
+# <checkout>/.jax_cache: a fixed path, since the path is part of what the
+# persistent cache keys on (git ignores it)
+PERSISTENT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
+def use_persistent_cache() -> None:
+    """Keep XLA's compiled programs across processes (entry points call
+    this before their first compile).  ``JAX_COMPILATION_CACHE_DIR``, when
+    set, is JAX's own choice and wins; otherwise the cache lives at
+    ``PERSISTENT_CACHE_DIR``."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", PERSISTENT_CACHE_DIR)

@@ -517,12 +517,13 @@ def pagerank_fixpoint(
     """The damped power iteration over one pane (library/pagerank.py's
     kernel on the plus-times semiring).  There is no frontier — every
     iteration spreads all mass — so direction is a whole-run choice:
-    push scatter-adds in arrival order (the bit-exact historical path,
-    and the auto default: both lowerings measure within noise here),
-    pull segment-sums the dst-STABLE-sorted copy — the same per-
-    destination addend order, hence bit-identical (pinned by
-    tests/test_spmv.py).  ``use_pull`` is traced: flipping it reuses the
-    executable."""
+    push scatter-adds in arrival order (the historical lowering, and the
+    auto default: both lowerings measure within noise here), pull
+    segment-sums the dst-STABLE-sorted copy — the same per-destination
+    addend order, hence push and pull agree bit for bit (pinned by
+    tests/test_spmv.py; the pre-refactor kernel agrees within a few ulp,
+    since XLA may fuse its float arithmetic differently).  ``use_pull``
+    is traced: flipping it reuses the executable."""
     capacity, e_pad = op.capacity, op.e_pad
 
     def build():

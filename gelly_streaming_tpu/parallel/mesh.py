@@ -74,31 +74,17 @@ def block_rows(capacity: int, num_shards: int) -> int:
     return capacity // num_shards
 
 
-try:  # jax >= 0.5 exports shard_map at top level; older builds under
-    # jax.experimental (accessing the missing top-level name raises
-    # AttributeError from jax's deprecation shim)
-    _shard_map_impl = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
 def shard_map(fn, mesh: Mesh, in_specs, out_specs):
     """jax.shard_map with the replication (vma) check disabled.
 
     The framework's kernels run data-dependent ``while_loop``s whose carries
     change mesh-variance mid-loop (invariant labels become shard-varying after
     hooking local edges, then invariant again after pmin) — valid SPMD that the
-    static vma checker rejects.  Handles the check kwarg rename and the
-    export location change across jax versions.
+    static vma checker rejects.
     """
-    try:
-        return _shard_map_impl(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:
-        return _shard_map_impl(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def sharded(mesh: Mesh):

@@ -104,12 +104,9 @@ def _status_lines(status: dict) -> list:
 
 
 def main(argv=None) -> int:
-    # pin the platform from JAX_PLATFORMS before any device use (same
-    # contract as the example CLIs: with an out-of-tree PJRT plugin on the
-    # path, the env var alone does not stop the plugin probing its device)
-    from gelly_streaming_tpu.examples._cli import _honor_platform_env
+    from gelly_streaming_tpu.core import compile_cache
 
-    _honor_platform_env()
+    compile_cache.use_persistent_cache()
     parser = argparse.ArgumentParser(
         prog="gelly-serve",
         description="run N concurrent streaming-graph queries over one "

@@ -1,18 +1,23 @@
 """Loader for the native (C++) host-plane helpers.
 
 Builds ``native/edge_parser.cpp`` into a shared library on first use (g++ is in
-the image; pybind11 is not, so the boundary is a plain C ABI via ctypes) and
-exposes a typed wrapper.  Falls back cleanly to ``None`` when no compiler is
+the image; pybind11 is not, so the boundary is a plain C ABI via ctypes),
+named by a hash of the source it was built from, and exposes a typed
+wrapper.  Falls back cleanly to ``None`` when no compiler is
 available — callers keep a pure-numpy path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 from typing import Optional
+
+logger = logging.getLogger(__name__)
 
 _PKG_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _REPO_ROOT = os.path.dirname(_PKG_ROOT)
@@ -34,17 +39,17 @@ def _find_src():
 
 
 _SRC, _IS_REPO_LAYOUT = _find_src()
-_CACHE_DIR = os.path.join(
-    os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-    "gelly_streaming_tpu",
-)
-# Repo checkouts build under native/build; installed packages go straight to
-# the per-user cache (building into site-packages would leave an unowned
-# directory behind on uninstall).
-_BUILD_DIRS = (
-    [os.path.join(_REPO_ROOT, "native", "build"), _CACHE_DIR]
+# A checkout builds inside itself (native/build, which git ignores); an
+# installed package, whose site-packages may be read-only, builds in the
+# per-user cache.  Either way the library's name carries the source hash,
+# so a build from other bytes (another checkout's, a stale one) never loads.
+_BUILD_DIR = (
+    os.path.join(_REPO_ROOT, "native", "build")
     if _IS_REPO_LAYOUT
-    else [_CACHE_DIR]
+    else os.path.join(
+        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
+        "gelly_streaming_tpu",
+    )
 )
 
 _lock = threading.Lock()
@@ -139,34 +144,47 @@ _CTYPE_TOKENS = {
 }
 
 
+def _source_digest() -> str:
+    """Hash of the canonical source's bytes: the built library's name
+    carries it, so a library built from any other source is never loaded
+    (an mtime check would accept a stale build whose file is newer)."""
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()[:16]
+
+
 def _build() -> Optional[str]:
     try:
-        src_mtime = os.path.getmtime(_SRC)
+        digest = _source_digest()
     except OSError:
-        # source not shipped: use a prebuilt .so if present, else fall back
-        for d in _BUILD_DIRS:
-            so = os.path.join(d, "libgelly_ingest.so")
-            if os.path.exists(so):
-                return so
-        return None
-    for d in _BUILD_DIRS:
-        so = os.path.join(d, "libgelly_ingest.so")
-        if os.path.exists(so) and os.path.getmtime(so) >= src_mtime:
-            return so
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread", _SRC, "-o"
-    ]
-    for d in _BUILD_DIRS:
-        so = os.path.join(d, "libgelly_ingest.so")
+        return None  # no source shipped: callers keep the numpy path
+    so = os.path.join(_BUILD_DIR, f"libgelly_ingest-{digest}.so")
+    if os.path.exists(so):
+        return so
+    # compile to a private name, then rename: a concurrent process sees
+    # either no library or the whole one
+    tmp = f"{so}.{os.getpid()}.{threading.get_ident()}.tmp"
+    try:
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        subprocess.run(
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
+             _SRC, "-o", tmp],
+            check=True,
+            capture_output=True,
+            timeout=120,
+        )
+        os.replace(tmp, so)
+        return so
+    except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError) as e:
+        logger.warning(
+            "native ingest library unavailable (%s: %s); using the numpy path",
+            type(e).__name__,
+            e,
+        )
         try:
-            os.makedirs(d, exist_ok=True)
-            subprocess.run(
-                cmd + [so], check=True, capture_output=True, timeout=120
-            )
-            return so
-        except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError):
-            continue
-    return None
+            os.unlink(tmp)
+        except OSError:
+            pass
+        return None
 
 
 def load_ingest_lib():
@@ -180,13 +198,9 @@ def load_ingest_lib():
         if so is None:
             return None
         lib = ctypes.CDLL(so)
-        # Bind every declared export straight from the signature table.  A
-        # prebuilt .so may predate newer symbols, so each is bound only
-        # when present — callers keep their pure-numpy fallbacks instead
-        # of crashing on a missing attribute.
+        # bind every declared export straight from the signature table (the
+        # library is built from the current source, so all of them exist)
         for name, (arg_tokens, ret_token) in NATIVE_SIGNATURES.items():
-            if not hasattr(lib, name):
-                continue
             fn = getattr(lib, name)
             fn.argtypes = [_CTYPE_TOKENS[t] for t in arg_tokens]
             fn.restype = _CTYPE_TOKENS[ret_token]

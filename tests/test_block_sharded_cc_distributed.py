@@ -22,13 +22,7 @@ _WORKER = textwrap.dedent(
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:  # older jax: pre-init XLA flag instead
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4"
-        ).strip()
+    jax.config.update("jax_num_cpu_devices", 4)
 
     coord, pid, phase, ckpt = (
         sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]

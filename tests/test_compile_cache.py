@@ -113,3 +113,30 @@ def test_stats_shape():
         "recompiles",
     ):
         assert key in s
+
+
+def test_persistent_cache_defaults_to_the_checkout(monkeypatch):
+    import os
+
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        compile_cache.use_persistent_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            root, ".jax_cache"
+        )
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_persistent_cache_env_dir_wins(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is JAX's own: the helper sets nothing."""
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    was = jax.config.jax_compilation_cache_dir
+    compile_cache.use_persistent_cache()
+    assert jax.config.jax_compilation_cache_dir == was

@@ -64,3 +64,25 @@ def test_stub_guard_rejects_code_carrying_copy(tmp_path):
     missing = tmp_path / "missing_include.cpp"
     missing.write_text("// only comments, no include\n")
     assert not native_mod.stub_is_reference_only(str(missing))
+
+
+def test_library_is_keyed_on_the_source_bytes(tmp_path, monkeypatch):
+    """A library built from other source bytes is never loaded: the build's
+    name carries the source's hash, so an edited source builds anew."""
+    import shutil
+
+    import pytest
+
+    if shutil.which("g++") is None:
+        pytest.skip("no C++ toolchain in this image")
+    src = tmp_path / "edge_parser.cpp"
+    shutil.copyfile(CANONICAL, src)
+    monkeypatch.setattr(native_mod, "_SRC", str(src))
+    monkeypatch.setattr(native_mod, "_BUILD_DIR", str(tmp_path / "build"))
+    first = native_mod._build()
+    assert first.endswith(f"-{native_mod._source_digest()}.so")
+    assert native_mod._build() == first  # found by name, not rebuilt
+    with open(src, "a") as f:
+        f.write("// edited\n")
+    second = native_mod._build()
+    assert second != first and os.path.exists(second)
