@@ -215,16 +215,36 @@ class SummaryAggregation:
     def _combine_partials(self, partials, cfg: StreamConfig):
         return self._fold_partials(partials, self._combine_j, self._tree_fanin(cfg))
 
+    # The two executables jit functions of stable names, so the device
+    # trace reads ``jit_gelly_fold`` / ``jit_gelly_combine`` whatever the
+    # descriptor's methods are called.
+
     @property
     def _update_j(self):
+        def build_fold():
+            update = self.update
+
+            def gelly_fold(state, src, dst, val, mask):
+                return update(state, src, dst, val, mask)
+
+            return gelly_fold
+
         return compile_cache.cached_jit(
-            ("agg_update", self.cache_token), lambda: self.update
+            ("agg_update", self.cache_token), build_fold
         )
 
     @property
     def _combine_j(self):
+        def build_combine():
+            combine = self.combine
+
+            def gelly_combine(a, b):
+                return combine(a, b)
+
+            return gelly_combine
+
         return compile_cache.cached_jit(
-            ("agg_combine", self.cache_token), lambda: self.combine
+            ("agg_combine", self.cache_token), build_combine
         )
 
     # -- packed-wire fast path ------------------------------------------------
@@ -1039,16 +1059,18 @@ class SummaryAggregation:
                     out[:n] = a[sel]
                     return out
 
-                state = self.initial_state(cfg)
-                state = self._update_j(
-                    state,
-                    jnp.asarray(pad(pane.src), jnp.int32),
-                    jnp.asarray(pad(pane.dst), jnp.int32),
-                    None
-                    if pane.val is None
-                    else jax.tree.map(lambda a: jnp.asarray(pad(a)), pane.val),
-                    jnp.asarray(mask),
-                )
+                src, dst = pad(pane.src), pad(pane.dst)
+                val = None if pane.val is None else jax.tree.map(pad, pane.val)
+                tracing.end_cut()  # the window's host arrays are ready
+                with tracing.span("gelly.pane.put"):
+                    src = jnp.asarray(src, jnp.int32)
+                    dst = jnp.asarray(dst, jnp.int32)
+                    val = None if val is None else jax.tree.map(jnp.asarray, val)
+                    mask = jnp.asarray(mask)
+                with tracing.span("gelly.fold.dispatch"):
+                    state = self._update_j(
+                        self.initial_state(cfg), src, dst, val, mask
+                    )
                 partials.append(state)
             if not partials:
                 return None
@@ -1057,7 +1079,7 @@ class SummaryAggregation:
         def records() -> Iterator[tuple]:
             return self._merge_loop(
                 cfg,
-                stream_panes(stream, window_ms),
+                stream_panes(stream, window_ms, mark_close=True),
                 fold_pane,
                 checkpoint_path,
                 restore,
@@ -1156,21 +1178,23 @@ class SummaryAggregation:
         span_sampler = tracing.sampler(cfg, "merge")
         token = self.cache_token
         split = functools.partial(self._superpane_split_fn, cfg)
-        for pane in stream_panes(stream, window_ms):
+        for pane in stream_panes(stream, window_ms, mark_close=True):
             already_folded = (0 <= pane.window_id <= start_after) or (
                 pane.window_id == -1 and global_done
             )
             if already_folded:
+                tracing.drop_cut()
                 continue  # folded before the snapshot: replay-safe
             span = (
                 span_sampler.begin(pane.window_id)
                 if span_sampler is not None
                 else None
             )
-            t_item = time.perf_counter()
+            tracing.enter_window(pane.window_id, span)
             pane = self._maybe_bin_pane(cfg, pane)
             n = pane.num_edges
             if n == 0:
+                tracing.leave_window()
                 continue  # empty pane: the sync fold returns None too
             # the sync plane's pow2 pad, materialized as the offered row
             e_pad = max(1, 1 << (n - 1).bit_length())
@@ -1188,6 +1212,7 @@ class SummaryAggregation:
 
                 val = jax.tree.map(pad, pane.val)
             has_val = val is not None
+            tracing.end_cut()
             partial = yield FoldRequest(
                 key=(token, cfg, has_val, e_pad),
                 fold=self._superpane_fold_fn(cfg, has_val),
@@ -1199,34 +1224,33 @@ class SummaryAggregation:
                 window_id=pane.window_id,
                 edges=n,
             )
+            # the cohort round ran other jobs' windows on this thread
+            tracing.scope_window(pane.window_id, span)
             if partial is None:
                 # solo fallback: no same-key peers this round (or a
                 # protocol-naive resume) — fold the identical padded
                 # arrays through the plain plane's executable
-                partial = self._update_j(
-                    self.initial_state(cfg),
-                    jnp.asarray(src),
-                    jnp.asarray(dst),
-                    None if val is None else jax.tree.map(jnp.asarray, val),
-                    jnp.asarray(msk),
-                )
-            if running is None or self.transient_state:
-                running = partial
-            else:
-                running = self._combine_j(running, partial)
-            out = self.transform(running)
-            t_emit = time.perf_counter()
-            metrics.hist_record(
-                "window_close_to_emission_ms", (t_emit - t_item) * 1e3
-            )
-            if span is not None:
-                span.mark("dispatch", t_item, t_emit)
-                span.mark("emit", t_emit)
-                span_sampler.record(span, t_emit)
+                with tracing.span("gelly.pane.put"):
+                    put = (
+                        jnp.asarray(src),
+                        jnp.asarray(dst),
+                        None if val is None else jax.tree.map(jnp.asarray, val),
+                        jnp.asarray(msk),
+                    )
+                with tracing.span("gelly.fold.dispatch"):
+                    partial = self._update_j(self.initial_state(cfg), *put)
+            with tracing.span("gelly.combine.dispatch"):
+                if running is None or self.transient_state:
+                    running = partial
+                else:
+                    running = self._combine_j(running, partial)
+                out = self.transform(running)
             # Emit BEFORE snapshotting: a crash between the two re-emits
             # this window on recovery (at-least-once emission) instead of
             # dropping it (at-most-once would lose sink data).
+            emission = tracing.emit()
             yield out if isinstance(out, tuple) else (out,)
+            tracing.settle(emission)
             start_after = max(pane.window_id, start_after)
             global_done = global_done or pane.window_id == -1
             if checkpoint_path:
@@ -1630,35 +1654,37 @@ class SummaryAggregation:
                 pane.window_id == -1 and global_done
             )
             if already_folded:
+                tracing.drop_cut()
                 continue  # folded before the snapshot: replay-safe
             span = (
                 span_sampler.begin(pane.window_id)
                 if span_sampler is not None
                 else None
             )
-            t_item = time.perf_counter()
+            # the window's spans (cut, put, fold, combine here; sink wait
+            # and copy on the consumer's side) share its (job, window)
+            tracing.enter_window(pane.window_id, span)
             pane_summary = fold_pane(payload)
+            tracing.end_cut()
             if pane_summary is None:
+                tracing.leave_window()
                 continue
             # Merger: non-blocking running merge, one emission per window
             # close (SummaryAggregation.java:107-119).
-            if running is None or self.transient_state or fold_is_running:
-                running = pane_summary
-            else:
-                running = self._combine_j(running, pane_summary)
-            out = self.transform(running)
-            t_emit = time.perf_counter()
-            metrics.hist_record(
-                "window_close_to_emission_ms", (t_emit - t_item) * 1e3
-            )
-            if span is not None:
-                span.mark("dispatch", t_item, t_emit)
-                span.mark("emit", t_emit)
-                span_sampler.record(span, t_emit)
+            with tracing.span("gelly.combine.dispatch"):
+                if running is None or self.transient_state or fold_is_running:
+                    running = pane_summary
+                else:
+                    running = self._combine_j(running, pane_summary)
+                out = self.transform(running)
             # Emit BEFORE snapshotting: a crash between the two re-emits
             # this window on recovery (at-least-once emission) instead of
-            # dropping it (at-most-once would lose sink data).
+            # dropping it (at-most-once would lose sink data).  The
+            # emission (window, close, sampled span) goes to the record's
+            # consumer, which ends the window once the record is on host.
+            emission = tracing.emit()
             yield out if isinstance(out, tuple) else (out,)
+            tracing.settle(emission)
             start_after = max(pane.window_id, start_after)
             global_done = global_done or pane.window_id == -1
             if checkpoint_path:

@@ -215,6 +215,7 @@ def assign_ingestion_windows(
     every_edges: int = 0,
     every_ms: int = 0,
     clock=None,
+    mark_close: bool = False,
 ) -> Iterator[WindowPane]:
     """Tumbling panes for UNTIMED streams: the reference's default
     ingestion-time mode (SimpleEdgeStream.java:69-73; running emission per
@@ -236,8 +237,14 @@ def assign_ingestion_windows(
     panes are NOT replay-deterministic — a resume could skip edges the
     crashed run never folded — so checkpointed runs refuse ``every_ms``
     (enforced in SummaryAggregation.run / BlockShardedCC.run).
+
+    ``mark_close`` (the synchronous windowed planes, which consume panes on
+    the cutting thread) opens each pane's ``gelly.pane.cut`` span at the
+    window's close for the merge loop to take (utils/tracing.py).
     """
     import time as _time
+
+    from gelly_streaming_tpu.utils import tracing
 
     if bool(every_edges) == bool(every_ms):
         raise ValueError("set exactly one of every_edges / every_ms")
@@ -247,6 +254,13 @@ def assign_ingestion_windows(
     t0 = None
 
     for batch in batches:
+        if mark_close and every_edges and count:
+            # a batch reaching past the open window's end closes it: the
+            # window's close is this pull's return, so its cut opens before
+            # the batch is read (exact for full batches)
+            open_wid = (count - 1) // every_edges
+            if (count + batch.src.shape[0] - 1) // every_edges > open_wid:
+                tracing.begin_cut(open_wid)
         src, dst, val, _time_ignored = _batch_to_host(batch)
         if len(src) == 0:
             continue
@@ -262,9 +276,13 @@ def assign_ingestion_windows(
         panes.add(src, dst, val, None, wids)
         newest = int(wids.max())
         for wid in [w for w in panes.open_ids() if 0 <= w < newest]:
+            if mark_close:
+                tracing.begin_cut(wid)
             yield panes.close(wid)
 
     for wid in panes.open_ids():
+        if mark_close:
+            tracing.begin_cut(wid)
         yield panes.close(wid)
 
 
@@ -589,12 +607,15 @@ def _array_backed_panes(
         )
 
 
-def stream_panes(stream, window_ms: int) -> Iterator[WindowPane]:
+def stream_panes(
+    stream, window_ms: int, mark_close: bool = False
+) -> Iterator[WindowPane]:
     """The pane source for an aggregation over ``stream``: ingestion-time
     panes when the config asks for them, else event-time tumbling windows
     (untimed streams degrade to the single global pane there).  Shared by
     the simulated runtime, the mesh runner, and BlockShardedCC so the time
-    plane cannot diverge between execution paths."""
+    plane cannot diverge between execution paths.  ``mark_close`` passes
+    through to ``assign_ingestion_windows``."""
     cfg = stream.cfg
     if cfg.ingest_window_edges or cfg.ingest_window_ms:
         arrays = getattr(stream, "_wire_arrays", None)
@@ -615,6 +636,7 @@ def stream_panes(stream, window_ms: int) -> Iterator[WindowPane]:
             stream.batches(),
             cfg.ingest_window_edges,
             cfg.ingest_window_ms,
+            mark_close=mark_close,
         )
     return assign_tumbling_windows(
         stream.batches(),

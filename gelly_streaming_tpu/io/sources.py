@@ -20,7 +20,7 @@ from gelly_streaming_tpu.core.config import StreamConfig
 from gelly_streaming_tpu.core.stream import EdgeStream
 from gelly_streaming_tpu.core.types import EdgeBatch
 from gelly_streaming_tpu.io.interning import IdentityInterner, VertexInterner
-from gelly_streaming_tpu.utils import metrics
+from gelly_streaming_tpu.utils import metrics, tracing
 from gelly_streaming_tpu.utils.native import load_ingest_lib
 
 
@@ -362,13 +362,14 @@ class NetworkEdgeSource:
 
         self._refuse_if_not_open()
         validate_wire_width(width, self.cfg.vertex_capacity)
-        s, d = validate_wire_buffer(
-            buf,
-            self.batch,
-            width,
-            self.cfg.vertex_capacity,
-            decode_ids=True,
-        )
+        with tracing.span("gelly.push.decode"):
+            s, d = validate_wire_buffer(
+                buf,
+                self.batch,
+                width,
+                self.cfg.vertex_capacity,
+                decode_ids=True,
+            )
         self._accept(s, d, timeout, offset)
         return len(s)
 
@@ -465,7 +466,8 @@ class NetworkEdgeSource:
         # the push-to-fold latency histogram (how long a pushed batch
         # waited before the scheduler folded it).  ``release`` (decode-pool
         # batches only) rides along so the factory can return the arena.
-        self._q.put((s, d, time.perf_counter(), release), timeout=timeout)
+        with tracing.span("gelly.push.enqueue"):
+            self._q.put((s, d, time.perf_counter(), release), timeout=timeout)
         with self._lock:
             self._edges_in += len(s)
         wake = self.on_data
@@ -626,6 +628,10 @@ class NetworkEdgeSource:
                 self._edges_out += n
             left -= n
             yield EdgeBatch.from_host_arrays(zeros, zeros, pad_to=self.batch)
+        # the window the next batch's first edge falls in: what a wait in
+        # the queue's get is waiting to fill
+        w = self.cfg.ingest_window_edges
+        filling = self._resume_edges // w if w else -1
         while True:
             # end-of-stream must not cost a poll slice: once the source is
             # closed the queue can only drain, so a non-blocking get is
@@ -636,10 +642,11 @@ class NetworkEdgeSource:
             with self._lock:
                 closed = self._closed
             try:
-                if closed:
-                    s, d, t_pushed, release = self._q.get_nowait()
-                else:
-                    s, d, t_pushed, release = self._q.get(timeout=0.05)
+                with tracing.span("gelly.source.wait", window=filling):
+                    if closed:
+                        s, d, t_pushed, release = self._q.get_nowait()
+                    else:
+                        s, d, t_pushed, release = self._q.get(timeout=0.05)
             except queue.Empty:
                 with self._lock:
                     if self._closed and self._q.empty():
@@ -653,6 +660,8 @@ class NetworkEdgeSource:
             )
             with self._lock:
                 self._edges_out += len(s)
+                if w:
+                    filling = self._edges_out // w
             if release is not None:
                 # the arena's donation fence: the host batch aliases its
                 # arrays (the ArenaPool ownership rule), so the rows are

@@ -190,6 +190,9 @@ class Job:
         # scheduler-private like the iterator bookkeeping above
         self._fused_windows = 0  # guarded-by: _lock
         self._last_quantum_end: Optional[float] = None  # single-thread: scheduler
+        # the window of the last record pulled (its emission's window id):
+        # the window being filled next is this + 1
+        self._last_window = -1  # single-thread: scheduler
         self._trace_dump: Optional[List[dict]] = None  # guarded-by: _lock
 
     # -- read-side API -------------------------------------------------------

@@ -124,6 +124,9 @@ class JobManager:
         self._health_every = float(self.cfg.health_sample_s or 0.0)
         self._next_health = 0.0  # single-thread: scheduler
         self._keepup: Dict[str, metrics.KeepUpTracker] = {}  # single-thread: scheduler
+        # the last job this round skipped because its source had no
+        # closable window: the round's park is that job's source wait
+        self._source_waiter: Optional[Job] = None  # single-thread: scheduler
         # SLO burn-rate monitor (runtime/slo.py): started with the
         # scheduler when cfg.slos is non-empty, stopped at shutdown
         self._slo_monitor = None  # guarded-by: _lock
@@ -661,21 +664,38 @@ class JobManager:
         own thread, so sink latency lands on this job alone."""
 
         def pump():  # single-thread: per-job sink pump
+            tracing.scope_job(job.job_id)
             while True:
-                rec = job._out.get()
-                if rec is _SENTINEL:
+                item = job._out.get()
+                if item is _SENTINEL:
                     break
+                # (record, emission): the emission names the record's
+                # window, so the sink's spans (tracing.SPANS) share the
+                # window's (job, window) with its scheduler-side spans
+                rec, em = item
                 t0 = time.perf_counter()
+                if em is not None:
+                    tracing.scope_window(em.window, em.sampled)
                 try:
                     job.sink(rec)
                 except BaseException as e:
                     self._fail(job, e)
                     break
-                metrics.job_add(
-                    job.job_id,
-                    "job_sink_stall_s",
-                    time.perf_counter() - t0,
-                )
+                finally:
+                    tracing.scope_window(None)
+                t1 = time.perf_counter()
+                metrics.job_add(job.job_id, "job_sink_stall_s", t1 - t0)
+                if em is not None:
+                    tracing.finish(em, t1)
+                    if em.t_close is not None:
+                        # the window's close to its record on the host,
+                        # scoped explicitly: this thread carries no
+                        # set_hist_job tag
+                        metrics.hist_record(
+                            "window_close_to_emission_ms",
+                            (t1 - em.t_close) * 1e3,
+                            job=job.job_id,
+                        )
                 self._wake.set()  # queue space freed: the job is runnable
             self._mark_drained(job)
 
@@ -758,6 +778,7 @@ class JobManager:
             progressed = False
             # quanta parked at a FoldRequest this round, awaiting a cohort
             pending: List[_Quantum] = []  # single-thread: scheduler
+            self._source_waiter = None
             for job in jobs:
                 try:
                     progressed |= self._run_quantum(job, pending)
@@ -787,8 +808,19 @@ class JobManager:
             if not progressed:
                 # nothing runnable: park until a submit/resume/cancel or a
                 # consumer freeing queue space wakes us (short cap so a
-                # missed wake degrades to polling, never to a wedge)
-                self._wake.wait(0.05)
+                # missed wake degrades to polling, never to a wedge).  A
+                # job skipped for its source makes the park a source wait
+                # for the window it is filling.
+                waiter = self._source_waiter
+                if waiter is None:
+                    self._wake.wait(0.05)
+                else:
+                    with tracing.span(
+                        "gelly.source.wait",
+                        job=waiter.job_id,
+                        window=waiter._last_window + 1,
+                    ):
+                        self._wake.wait(0.05)
                 self._wake.clear()
 
     def _run_quantum(
@@ -828,6 +860,7 @@ class JobManager:
             try:
                 if not ready():
                     metrics.job_add(job.job_id, "job_source_wait_skips", 1)
+                    self._source_waiter = job
                     return False
             except BaseException as e:
                 self._fail(job, e)
@@ -864,6 +897,7 @@ class JobManager:
         # histograms recorded deep inside the merge loops / network source
         # (close-to-emission, push-to-fold) land in this job's rows too
         prev_scope = metrics.set_hist_job(job.job_id)
+        prev_job = tracing.scope_job(job.job_id)
         try:
             while True:
                 if send is _FRESH:
@@ -896,7 +930,7 @@ class JobManager:
                         job._it = iter(build())
                         q.t_prev = time.perf_counter()
                     try:
-                        rec = next(job._it)
+                        rec, em = self._pull(job, _FRESH)
                     except StopIteration:
                         with self._lock:
                             job._transition(JobState.DRAINING)
@@ -911,7 +945,7 @@ class JobManager:
                     partial, send = send, _FRESH
                     q.t_prev = time.perf_counter()
                     try:
-                        rec = job._it.send(partial)
+                        rec, em = self._pull(job, partial)
                     except StopIteration:
                         with self._lock:
                             job._transition(JobState.DRAINING)
@@ -944,15 +978,25 @@ class JobManager:
                     metrics.job_add(
                         job.job_id, "job_edges", job.edges_per_record
                     )
+                if em is not None:
+                    job._last_window = em.window
                 # sole producer is this thread and fullness was checked
-                # above, so put_nowait cannot raise
-                job._out.put_nowait(rec)
+                # above, so put_nowait cannot raise.  A sink's pump gets
+                # the record's emission with it; a sink-less job's window
+                # ends here.
+                if job.sink is not None:
+                    job._out.put_nowait((rec, em))
+                else:
+                    if em is not None:
+                        tracing.finish(em, em.t_emit)
+                    job._out.put_nowait(rec)
                 metrics.job_high_water(
                     job.job_id, "job_queue_depth_hwm", job._out.qsize()
                 )
                 q.pulled += 1
         finally:
             metrics.set_hist_job(prev_scope)
+            tracing.scope_job(prev_job)
         if q.pulled:
             # scheduler queue wait: the gap from this job's previous
             # PRODUCTIVE quantum to this one's start — what a closed
@@ -970,6 +1014,17 @@ class JobManager:
             metrics.job_add(job.job_id, "job_sched_rounds", 1)
             job._last_quantum_end = time.perf_counter()
         return bool(q.pulled)
+
+    def _pull(self, job: Job, send):  # single-thread: scheduler
+        """One pull of the job's records iterator (``send`` resumes a
+        parked fold) under a ``gelly.sched.pull`` span, tagged with the
+        pulled record's window; returns ``(record, emission or None)``."""
+        with tracing.span("gelly.sched.pull") as pull:
+            rec = next(job._it) if send is _FRESH else job._it.send(send)
+            em = tracing.claim()
+            if em is not None:
+                pull.tag(window=em.window)
+        return rec, em
 
     def _dispatch_cohorts(
         self, pending: "List[_Quantum]"

@@ -62,7 +62,7 @@ from gelly_streaming_tpu.core.config import (
 from gelly_streaming_tpu.runtime import protocol
 from gelly_streaming_tpu.runtime.job import AdmissionError, Job, JobState
 from gelly_streaming_tpu.runtime.manager import JobManager
-from gelly_streaming_tpu.utils import events, metrics
+from gelly_streaming_tpu.utils import events, metrics, tracing
 
 
 # server-side synthetic streams ("generate" submits) materialize host
@@ -183,34 +183,44 @@ def record_leaves(rec) -> list:
     import jax
 
     out: list = []
+    _collect_leaves(rec, out, jax.Array)
+    # the wait for the device to compute the leaves, apart from their copy
+    # to host: a long wait is the device still busy, a long copy the host
+    # moving bytes while the device may sit idle
+    with tracing.span("gelly.sink.wait"):
+        jax.block_until_ready([x for x in out if isinstance(x, jax.Array)])
+    with tracing.span("gelly.sink.copy"):
+        return [np.asarray(x) for x in out]
 
-    def walk(x):
-        if isinstance(x, (tuple, list)):
-            for item in x:
-                walk(item)
-            return
-        if isinstance(x, dict):
-            for key in sorted(x):
-                walk(x[key])
-            return
-        if isinstance(x, (np.ndarray, np.generic, int, float, bool, jax.Array)):
-            out.append(np.asarray(x))
-            return
-        state = getattr(x, "__dict__", None)
-        if state:
-            for key in sorted(state):
-                walk(state[key])
-            return
-        arr = np.asarray(x)
-        if arr.dtype == object:
-            raise TypeError(
-                f"record leaf of type {type(x).__name__} has no array "
-                "representation; the results wire format carries arrays only"
-            )
-        out.append(arr)
 
-    walk(rec)
-    return out
+def _collect_leaves(x, out: list, device_array) -> None:
+    """``record_leaves``' walk, appending leaves to ``out`` as they are
+    (device arrays stay on the device).  A module-level function, not a
+    closure: a recursive closure is a reference cycle, which would keep
+    every record's device leaves alive until the garbage collector ran."""
+    if isinstance(x, (tuple, list)):
+        for item in x:
+            _collect_leaves(item, out, device_array)
+        return
+    if isinstance(x, dict):
+        for key in sorted(x):
+            _collect_leaves(x[key], out, device_array)
+        return
+    if isinstance(x, (np.ndarray, np.generic, int, float, bool, device_array)):
+        out.append(x)
+        return
+    state = getattr(x, "__dict__", None)
+    if state:
+        for key in sorted(state):
+            _collect_leaves(state[key], out, device_array)
+        return
+    arr = np.asarray(x)
+    if arr.dtype == object:
+        raise TypeError(
+            f"record leaf of type {type(x).__name__} has no array "
+            "representation; the results wire format carries arrays only"
+        )
+    out.append(arr)
 
 
 class _TokenBucket:
@@ -287,6 +297,7 @@ class _ServedJob:
         queue fills, and the scheduler skips that one job's rounds: the
         slow-consumer isolation boundary, end to end."""
         leaves = record_leaves(rec)
+        window = tracing.current_window()
         if not self._first_emit_done:
             self._first_emit_done = True
             # scoped rows only: the scheduler already recorded this job's
@@ -302,12 +313,13 @@ class _ServedJob:
                 self._cond.wait(0.1)
             if self._abandoned:
                 return
-            self._records.append(leaves)
+            self._records.append((window, leaves))
             self._cond.notify_all()
 
     def fetch(self, max_records: int, timeout_s: float, max_bytes: int):
-        """Up to ``max_records`` / ``max_bytes`` of buffered records
-        (blocking up to ``timeout_s`` for the first), plus (state, eos).
+        """Up to ``max_records`` / ``max_bytes`` of buffered records as
+        ``(window, leaves)`` pairs (blocking up to ``timeout_s`` for the
+        first), plus (state, eos).
 
         The BYTE bound is the real contract: records are popped
         destructively and the reply must fit the client's frame cap — an
@@ -326,8 +338,8 @@ class _ServedJob:
                     and len(out) < max_records
                     and nbytes < max_bytes
                 ):
-                    leaves = self._records.popleft()
-                    out.append(leaves)
+                    window, leaves = self._records.popleft()
+                    out.append((window, leaves))
                     nbytes += sum(leaf.nbytes for leaf in leaves)
                 self._cond.notify_all()  # sink may be waiting on space
                 have_more = bool(self._records)
@@ -996,6 +1008,19 @@ class StreamServer:
         raise _Refused("admission", msg)
 
     def _h_push(self, tenant, header, payload):
+        # the push's spans (tracing.SPANS: the frame, its decode and its
+        # enqueue) carry the job from this thread's scope
+        prev = tracing.scope_job(self._job_key(tenant, header.get("job")))
+        try:
+            offset = header.get("offset")
+            with tracing.span(
+                "gelly.push", offset=offset if isinstance(offset, int) else None
+            ):
+                return self._push(tenant, header, payload)
+        finally:
+            tracing.scope_job(prev)
+
+    def _push(self, tenant, header, payload):
         sj = self._served(tenant, header)
         if sj.source is None:
             raise _Refused(
@@ -1126,9 +1151,10 @@ class StreamServer:
 
         source.check_open()
         try:
-            s, d, release = pool.decode(
-                buf, width, source.batch, sj.cfg.vertex_capacity
-            )
+            with tracing.span("gelly.push.decode"):
+                s, d, release = pool.decode(
+                    buf, width, source.batch, sj.cfg.vertex_capacity
+                )
         except DecodePoolClosed:
             # same typed refusal the Python path gives a push that races
             # the server's stop
@@ -1218,7 +1244,9 @@ class StreamServer:
         max_bytes = (
             min(self.cfg.max_frame_bytes, protocol.DEFAULT_MAX_PAYLOAD) // 2
         )
-        records, state, eos = sj.fetch(max_records, timeout_s, max_bytes)
+        job = self._job_key(tenant, sj.name)
+        with tracing.span("gelly.results.wait", job=job):
+            records, state, eos = sj.fetch(max_records, timeout_s, max_bytes)
         # raw leaf framing (ISSUE 14): dtype/shape metadata rides the JSON
         # header, the payload is the leaves' raw bytes concatenated in
         # order.  The previous npz container cost ~0.4 ms of zipfile work
@@ -1226,15 +1254,22 @@ class StreamServer:
         # serving data plane's fold-phase budget at 4+ fetching clients;
         # the raw frame is a single buffer join, ~15x cheaper, and the
         # byte payload is identical information (same leaves, same order).
-        leafmeta = [
-            [[leaf.dtype.str, list(leaf.shape)] for leaf in leaves]
-            for leaves in records
-        ]
-        payload_out = b"".join(
-            np.ascontiguousarray(leaf).tobytes()
-            for leaves in records
-            for leaf in leaves
-        )
+        with tracing.span(
+            "gelly.results.frame",
+            job=job,
+            window=records[0][0] if records else None,
+            records=len(records),
+        ) as frame:
+            leafmeta = [
+                [[leaf.dtype.str, list(leaf.shape)] for leaf in leaves]
+                for _window, leaves in records
+            ]
+            payload_out = b"".join(
+                np.ascontiguousarray(leaf).tobytes()
+                for _window, leaves in records
+                for leaf in leaves
+            )
+            frame.tag(bytes=len(payload_out))
         metrics.tenant_add(
             tenant.tenant, "tenant_records_fetched", len(records)
         )

@@ -805,7 +805,8 @@ def reset_alerts() -> None:
 # sample lists.  The canonical names:
 #
 #   submit_to_first_emission_ms   job admission -> first record delivered
-#   window_close_to_emission_ms   merge-loop pane receipt -> record yield
+#   window_close_to_emission_ms   window close -> record on the host (the
+#                                 sink pump, explicitly job-scoped)
 #   push_to_fold_ms               network ingest queue residency
 #   sched_queue_wait_ms           gap between a job's scheduler quanta
 #

@@ -2,7 +2,7 @@
 
 The reference delegates monitoring wholesale to the Flink runtime (latency
 tracking, operator metrics — PAPER.md §1); this TPU-native rebuild supplies
-that slice of the runtime itself.  Two primitives live here:
+that slice of the runtime itself.  Three primitives live here:
 
 * **WindowSpan / FlightRecorder** — each sampled window (or micro-batch)
   gets a trace id at the pack thread and accumulates monotonic-clock stage
@@ -23,14 +23,27 @@ that slice of the runtime itself.  Two primitives live here:
   the old ``WindowLatencyRecorder.percentile`` int-floor had is pinned
   fixed by tests/test_tracing.py's exact-value cases).
 
+* **span** — the served path's one span primitive, on the profiler's
+  clock.  Each step of a window (scheduler pull, pane cut, host->device
+  put, fold and combine dispatch, sink wait and copy, push and results
+  framing) opens a ``jax.profiler.TraceAnnotation`` tagged with the
+  window's ``(job, window)``, which lands in the profiler's host plane
+  beside the device trace when a profile is being taken and costs a
+  ``TraceMe.is_enabled()`` check otherwise.  On a sampled window the same
+  span also marks its flight-recorder stage (the ``SPANS`` table below),
+  so the ``trace`` verb and the FAILED-job post-mortem read the same
+  steps.  Spans never run inside native or jitted code and never wait on
+  the device themselves.
+
 A span's ``stages`` list is appended from several pipeline threads, but
 never concurrently: each stage's thread hands the window to the next
-through a queue (Prefetcher queues, the completion deque), and that
-handoff is the synchronization — the same ownership discipline transfer
-arenas ride.  Only the RING is shared for real (drain threads of many
-jobs write, server/status threads read), so only the ring is
-lock-guarded (the analyzer's lock-discipline pass pins the annotation;
-tests/analysis_corpus/{good,bad}_tracing.py is the corpus pair).
+through a queue (Prefetcher queues, the completion deque, a job's
+emission queue), and that handoff is the synchronization — the same
+ownership discipline transfer arenas ride.  Only the RING is shared for
+real (drain threads of many jobs write, server/status threads read), so
+only the ring is lock-guarded (the analyzer's lock-discipline pass pins
+the annotation; tests/analysis_corpus/{good,bad}_tracing.py is the
+corpus pair).
 """
 
 from __future__ import annotations
@@ -38,6 +51,7 @@ from __future__ import annotations
 import itertools
 import math
 import os
+import sys
 import threading
 import time
 from typing import List, Optional
@@ -432,23 +446,19 @@ class Sampler:
     to).
     """
 
-    __slots__ = ("plane", "rate", "_stride", "_seen", "_recorder")
+    __slots__ = ("plane", "rate", "_stride", "_seen")
 
     def __init__(self, plane: str, rate: float):
         self.plane = plane
         self.rate = float(rate)
         self._stride = max(1, round(1.0 / self.rate))
         self._seen = 0
-        self._recorder = flight_recorder()
 
     def begin(self, window_id: int) -> Optional[WindowSpan]:
         self._seen += 1
         if (self._seen - 1) % self._stride:
             return None
         return WindowSpan(next(_TRACE_IDS), self.plane, window_id)
-
-    def record(self, span: WindowSpan, t_end: Optional[float] = None) -> dict:
-        return self._recorder.record(span, t_end)
 
 
 _TRACE_IDS = itertools.count(1)
@@ -499,3 +509,250 @@ def record_event(plane: str, stage: str, t_start: float, **meta) -> None:
     if meta:
         span.annotate(**meta)
     flight_recorder().record(span)
+
+
+# ---------------------------------------------------------------------------
+# served-path spans on the profiler's clock
+
+
+#: The served windowed plane's span vocabulary: span name -> (the
+#: flight-recorder stage it marks on a sampled window, or None; the thread
+#: it runs on).  Every span of one window carries the same ``(job,
+#: window)``: scheduler and sink-pump spans read it from their thread's
+#: scope (``scope_job`` / ``enter_window`` / ``scope_window``), connection
+#: spans carry it explicitly.
+SPANS = {
+    # one scheduler pull that yields a record
+    "gelly.sched.pull": (None, "scheduler"),
+    # blocked on the job's source, tagged with the window being filled:
+    # in the source queue's get, or parked while no window is closable
+    "gelly.source.wait": (None, "scheduler"),
+    # the window's close (the return of its last source pull) until its
+    # host arrays are ready: concatenate, bin, pad
+    "gelly.pane.cut": ("pack", "scheduler"),
+    # the host->device put of the pane's src / dst / mask
+    "gelly.pane.put": ("transfer", "scheduler"),
+    # the host enqueue of the fold executable
+    "gelly.fold.dispatch": ("dispatch", "scheduler"),
+    # the host enqueue of the running merge (combine) and the transform
+    "gelly.combine.dispatch": ("dispatch", "scheduler"),
+    # waiting for the record's device leaves to be computed
+    "gelly.sink.wait": ("drain", "sink pump"),
+    # the record's device->host copy
+    "gelly.sink.copy": ("emit", "sink pump"),
+    # one push frame (job, offset); its children decode the wire buffer
+    # and enqueue the batch into the job's source (full-queue
+    # backpressure waits included)
+    "gelly.push": (None, "connection"),
+    "gelly.push.decode": (None, "connection"),
+    "gelly.push.enqueue": (None, "connection"),
+    # a results fetch: waiting for records, then framing them (leaf
+    # metadata and the payload join)
+    "gelly.results.wait": (None, "connection"),
+    "gelly.results.frame": (None, "connection"),
+}
+
+_TRACEME = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def profiling() -> bool:
+    """True while a profile is being taken in this process.  Until jax is
+    imported this is one dict lookup: a process that never imported jax
+    cannot be profiling (the client and the server's start-up never do)."""
+    global _TRACEME
+    tm = _TRACEME
+    if tm is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _TRACEME = tm = TraceAnnotation
+    return tm.is_enabled()
+
+
+# The thread's window scope: the job its scheduler pull or sink pump
+# serves, the window in hand and that window's sampled WindowSpan, the
+# window's open cut span, and the emission handed to the record's
+# consumer.  Thread-local, so it needs no lock: a window's scheduler-side
+# spans all run on the scheduler thread, its sink spans on its job's pump.
+_SCOPE = threading.local()
+
+
+class Span:
+    """One open span of the ``SPANS`` vocabulary (see :func:`span`)."""
+
+    __slots__ = ("t0", "_stage", "_sampled", "_me")
+
+    def __init__(self, name, job=None, window=None, **stats):
+        self.t0 = time.perf_counter()
+        self._stage = SPANS[name][0]
+        self._sampled = getattr(_SCOPE, "sampled", None)
+        self._me = None
+        if profiling():
+            if job is None:
+                job = getattr(_SCOPE, "job", None)
+            if window is None:
+                window = getattr(_SCOPE, "window", None)
+            if job is not None:
+                stats["job"] = job
+            if window is not None:
+                stats["window"] = int(window)
+            me = _TRACEME(
+                name, **{k: v for k, v in stats.items() if v is not None}
+            )
+            me.__enter__()
+            self._me = me
+
+    def tag(self, **stats) -> None:
+        """Add event stats known only after the span opened."""
+        if self._me is not None:
+            self._me.set_metadata(
+                **{k: v for k, v in stats.items() if v is not None}
+            )
+
+    def close(self) -> None:
+        """End the span (idempotent); on a sampled window, mark its stage."""
+        me = self._me
+        if me is not None:
+            self._me = None
+            me.__exit__(None, None, None)
+        if self._stage is not None and self._sampled is not None:
+            self._sampled.mark(self._stage, self.t0)
+        self._stage = None
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+def span(name: str, *, job=None, window=None, **stats) -> Span:
+    """Open span ``name`` (a ``SPANS`` key) now; use as a context manager
+    or ``close()`` it.  ``job`` / ``window`` default to the thread's scope,
+    as does the sampled WindowSpan whose stage it marks; further keyword
+    arguments become event stats."""
+    return Span(name, job, window, **stats)
+
+
+def scope_job(job: Optional[str]) -> Optional[str]:
+    """Tag this thread's spans with ``job``; returns the previous tag."""
+    old = getattr(_SCOPE, "job", None)
+    _SCOPE.job = job
+    return old
+
+
+def scope_window(window: Optional[int], sampled=None) -> None:
+    """Tag this thread's spans with ``window`` and its sampled span."""
+    _SCOPE.window, _SCOPE.sampled = window, sampled
+
+
+def current_window() -> Optional[int]:
+    return getattr(_SCOPE, "window", None)
+
+
+def begin_cut(window: int) -> None:
+    """The pane cutter: ``window`` closes now.  Opens its
+    ``gelly.pane.cut`` for the merge loop to take (a cut already open for
+    the same window keeps its earlier start)."""
+    pending = getattr(_SCOPE, "cut", None)
+    if pending is not None:
+        if pending[0] == window:
+            return
+        pending[1].close()
+    _SCOPE.cut = (window, Span("gelly.pane.cut", window=window))
+
+
+def drop_cut() -> None:
+    """A closed pane the merge loop skips (folded before a restore): end
+    the cut the cutter opened for it."""
+    pending = getattr(_SCOPE, "cut", None)
+    if pending is not None:
+        _SCOPE.cut = None
+        pending[1].close()
+
+
+def enter_window(window: int, sampled: Optional[WindowSpan] = None) -> None:
+    """The merge loop at a pane's receipt: take the cutter's open cut span
+    for ``window`` (or open one now), scope the thread's spans to the
+    window, and anchor the sampled span at the window's close."""
+    pending = getattr(_SCOPE, "cut", None)
+    _SCOPE.cut = None
+    if pending is not None and pending[0] == window:
+        cut = pending[1]
+    else:
+        if pending is not None:
+            pending[1].close()
+        cut = Span("gelly.pane.cut", window=window)
+    if sampled is not None:
+        sampled.t0 = cut.t0
+        cut._sampled = sampled
+    _SCOPE.window, _SCOPE.sampled = window, sampled
+    _SCOPE.open_cut = cut
+    _SCOPE.t_close = cut.t0
+
+
+def end_cut() -> None:
+    """The window's host arrays are ready: close its cut (idempotent)."""
+    cut = getattr(_SCOPE, "open_cut", None)
+    if cut is not None:
+        _SCOPE.open_cut = None
+        cut.close()
+
+
+def leave_window() -> None:
+    """The window produced no record: drop the thread's window scope."""
+    end_cut()
+    _SCOPE.window = _SCOPE.sampled = None
+
+
+class Emission:
+    """What the merge loop hands a record's consumer with the record: the
+    window id, its close (the start of its ``gelly.pane.cut``, on the
+    ``time.perf_counter`` clock), its sampled span, and the yield time."""
+
+    __slots__ = ("window", "t_close", "sampled", "t_emit")
+
+    def __init__(self, window, t_close, sampled, t_emit):
+        self.window = window
+        self.t_close = t_close
+        self.sampled = sampled
+        self.t_emit = t_emit
+
+
+def emit() -> Emission:
+    """The merge loop at a record's yield: leave the window's scope and
+    hand the emission to whoever pulls the record (:func:`claim`)."""
+    end_cut()
+    em = Emission(
+        _SCOPE.window,
+        getattr(_SCOPE, "t_close", None),
+        _SCOPE.sampled,
+        time.perf_counter(),
+    )
+    _SCOPE.window = _SCOPE.sampled = None
+    _SCOPE.emission = em
+    return em
+
+
+def claim() -> Optional[Emission]:
+    """The consumer, right after pulling a record on the same thread: the
+    record's emission, or None when its plane hands none over."""
+    em = getattr(_SCOPE, "emission", None)
+    if em is not None:
+        _SCOPE.emission = None
+    return em
+
+
+def settle(em: Emission) -> None:
+    """The merge loop, resumed after a yield: a record whose emission no
+    consumer claimed ends at its yield."""
+    if getattr(_SCOPE, "emission", None) is em:
+        _SCOPE.emission = None
+        finish(em, em.t_emit)
+
+
+def finish(em: Emission, t_end: Optional[float] = None) -> None:
+    """Record the emission's sampled span, if any, into the recorder."""
+    if em.sampled is not None:
+        flight_recorder().record(em.sampled, t_end)

@@ -837,6 +837,28 @@ def test_client_deadline_fails_loudly_not_forever():
                     pass
 
 
+def test_record_leaves_frees_device_leaves_without_the_collector():
+    """A record's device leaves are freed as soon as the record and its
+    host leaves are dropped: nothing in the walk holds them in a reference
+    cycle that only the garbage collector would break."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    gc.disable()
+    try:
+        rec = (jnp.arange(4093, dtype=jnp.int32) + 7, {"b": jnp.ones((4091,))})
+        leaves = record_leaves(rec)
+        assert [leaf.shape for leaf in leaves] == [(4093,), (4091,)]
+        assert all(isinstance(leaf, np.ndarray) for leaf in leaves)
+        del rec, leaves
+        shapes = {a.shape for a in jax.live_arrays()}
+        assert (4093,) not in shapes and (4091,) not in shapes
+    finally:
+        gc.enable()
+
+
 # ---------------------------------------------------------------------------
 # observability plane (ISSUE 9): metrics / trace verbs, FAILED post-mortems,
 # gelly-top

@@ -241,20 +241,79 @@ def _run_cc(trace_sample, async_windows=0, n=1 << 13, cap=1 << 10, bs=512):
     return [np.asarray(r[0].parent) for r in recs]
 
 
+def _served_cc(trace_sample=0.0, name="spans", n=1 << 14, w=1 << 12):
+    """One served push job (JobManager + StreamServer on loopback): its
+    records as the client receives them (lists of host leaves)."""
+    from gelly_streaming_tpu.core.config import ServerConfig
+    from gelly_streaming_tpu.runtime.client import GellyClient
+    from gelly_streaming_tpu.runtime.manager import JobManager
+    from gelly_streaming_tpu.runtime.server import StreamServer
+
+    cap, bs = 1 << 10, w // 2
+    rng = np.random.default_rng(11)
+    src = rng.integers(0, cap, n).astype(np.int32)
+    dst = rng.integers(0, cap, n).astype(np.int32)
+    with JobManager() as jm, StreamServer(jm, ServerConfig()) as server:
+        with GellyClient("127.0.0.1", server.port) as c:
+            spec = dict(
+                name=name, query="cc", capacity=cap, window_edges=w, batch=bs
+            )
+            if trace_sample:
+                spec["trace_sample"] = trace_sample
+            c.submit(**spec)
+            c.push_edges(name, src, dst, batch=bs, capacity=cap)
+            return list(c.iter_results(name, deadline_s=240))
+
+
+def _profiled(run, trace_dir):
+    """``run()`` under the JAX profiler; returns its result and the
+    ``SPANS`` events of the trace's host planes as ``(name, start_ns,
+    end_ns, stats)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(trace_dir)):
+        out = run()
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    events = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name in tracing.SPANS
+    ]
+    return out, events
+
+
 @pytest.mark.timeout_cap(300)
-@pytest.mark.parametrize("depth", [0, 3])
-def test_tracing_off_is_no_op_and_emissions_bit_identical(depth):
-    """The overhead-regression satellite: trace_sample=0 leaves the
-    flight recorder untouched and adds zero compiles, and a traced run's
-    emissions are bit-identical to the untraced oracle's."""
-    base = _run_cc(0.0, async_windows=depth)  # warmup: compiles land here
+@pytest.mark.parametrize("depth", [0, 3, "served"])
+def test_tracing_off_is_no_op_and_emissions_bit_identical(depth, tmp_path):
+    """The overhead-regression satellite: with no profiler and
+    trace_sample=0 the flight recorder stays untouched and no compile is
+    added, and a traced run's emissions are bit-identical to the untraced
+    oracle's.  On the served plane the traced run also runs under the
+    profiler, so every span is open there."""
+    if depth == "served":
+        run = lambda sample: [  # noqa: E731
+            np.asarray(r[0]) for r in _served_cc(sample)
+        ]
+    else:
+        run = lambda sample: _run_cc(sample, async_windows=depth)  # noqa: E731
+    base = run(0.0)  # warmup: compiles land here
     recorded_before = tracing.span_stats()["recorded"]
     cc_before = metrics.compile_cache_stats()
-    off = _run_cc(0.0, async_windows=depth)
+    off = run(0.0)
     cc_mid = metrics.compile_cache_stats()
     assert tracing.span_stats()["recorded"] == recorded_before
     assert cc_mid["compiles"] == cc_before["compiles"]
-    on = _run_cc(1.0, async_windows=depth)
+    if depth == "served":
+        on, events = _profiled(lambda: run(1.0), tmp_path)
+        assert events
+    else:
+        on = run(1.0)
     cc_after = metrics.compile_cache_stats()
     # tracing on: same executables (0 new compiles, 0 recompiles)...
     assert cc_after["compiles"] == cc_mid["compiles"]
@@ -290,15 +349,85 @@ def test_async_spans_cover_all_stages_and_sum_to_total():
 
 @pytest.mark.timeout_cap(300)
 def test_sync_plane_spans_and_close_to_emission_histogram():
+    """A served job on the sync plane: each sampled window's span runs from
+    its close to its record on the host (the sink pump records it), and
+    the close-to-emission histogram holds one job-scoped sample a window,
+    recorded once the sink returned."""
     tracing.reset_tracing()
     metrics.reset_histograms()
-    out = _run_cc(1.0, async_windows=0)
+    out = _served_cc(1.0, name="hist")
     spans = tracing.flight_recorder().last(64)
     assert len(spans) == len(out)
     assert all(s["plane"] == "merge" for s in spans)
-    hist = metrics.hist_snapshot()["global"]["window_close_to_emission_ms"]
-    assert hist["count"] == len(out)
-    assert hist["p99_ms"] >= hist["p50_ms"] > 0
+    assert [s["window"] for s in spans] == list(range(len(out)))
+    for s in spans:
+        stages = {x["stage"] for x in s["stages"]}
+        assert {"pack", "transfer", "dispatch", "drain", "emit"} <= stages
+        assert s["stages"][0]["stage"] == "pack"
+        assert s["stages"][0]["start_ms"] == 0.0  # anchored at the close
+    snap = metrics.hist_snapshot()
+    for hist in (
+        snap["global"]["window_close_to_emission_ms"],
+        snap["jobs"]["default/hist"]["window_close_to_emission_ms"],
+    ):
+        assert hist["count"] == len(out)
+        assert hist["p99_ms"] >= hist["p50_ms"] > 0
+
+
+@pytest.mark.timeout_cap(300)
+def test_served_spans_share_job_and_window_on_the_profilers_clock(tmp_path):
+    """Under the profiler, a served push job (2^12-edge windows) emits every
+    span of the vocabulary; each emitted window has its cut, put, both
+    dispatches and the sink's wait and copy under one (job, window), and
+    its sink spans start after its cut."""
+    out, events = _profiled(lambda: _served_cc(0.0, name="prof"), tmp_path)
+    assert {name for name, *_ in events} == set(tracing.SPANS)
+    per_window: dict = {}
+    for name, t0, t1, stats in events:
+        if "window" in stats and stats.get("job") == "default/prof":
+            per_window.setdefault(stats["window"], {}).setdefault(
+                name, []
+            ).append((t0, t1))
+    need = {
+        "gelly.pane.cut",
+        "gelly.pane.put",
+        "gelly.fold.dispatch",
+        "gelly.combine.dispatch",
+        "gelly.sink.wait",
+        "gelly.sink.copy",
+        "gelly.sched.pull",
+    }
+    for w in range(len(out)):
+        got = per_window.get(w, {})
+        assert need <= set(got), (w, sorted(got))
+        cut_start = got["gelly.pane.cut"][-1][0]
+        for name in ("gelly.sink.wait", "gelly.sink.copy"):
+            assert got[name][0][0] >= cut_start, (w, name)
+    # the results frames name the window of their first record
+    frames = [s for n, *_, s in events if n == "gelly.results.frame"]
+    assert any(f.get("window") == 0 and f["records"] >= 1 for f in frames)
+    pushes = [s for n, *_, s in events if n == "gelly.push"]
+    assert all(p["job"] == "default/prof" for p in pushes)
+    assert sorted(p["offset"] for p in pushes)[0] == 0
+
+
+@pytest.mark.parametrize("query", ["cc", "degree"])
+def test_fold_and_combine_lower_under_stable_names(query):
+    """The fold and combine executables carry stable names on every plane
+    that uses them, whatever the descriptor's methods are called."""
+    import jax.numpy as jnp
+
+    from gelly_streaming_tpu.runtime.server import descriptor_for
+
+    cfg = StreamConfig(vertex_capacity=1 << 8, batch_size=64)
+    agg = descriptor_for(query)
+    state = agg.initial_state(cfg)
+    ids = jnp.zeros((64,), jnp.int32)
+    mask = jnp.ones((64,), bool)
+    fold = agg._update_j.lower(state, ids, ids, None, mask).as_text()
+    combine = agg._combine_j.lower(state, state).as_text()
+    assert fold.splitlines()[0].startswith("module @jit_gelly_fold ")
+    assert combine.splitlines()[0].startswith("module @jit_gelly_combine ")
 
 
 @pytest.mark.timeout_cap(300)
