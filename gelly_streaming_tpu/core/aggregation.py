@@ -1034,7 +1034,17 @@ class SummaryAggregation:
                 )
             )
 
-        def fold_pane(pane: WindowPane):
+        # An order-free fold obeys the combine contract the partitioned
+        # folds rely on, so fold(S, E) == combine(S, fold(initial, E)): with
+        # one partition each pane folds straight into the running summary
+        # and the Merger skips the combine (for CC a second union-find over
+        # all C vertices).  The summary is not donated: the previous
+        # window's record may still be on its way to the host.
+        into_running = (
+            self.order_free and not self.transient_state and n_parts == 1
+        )
+
+        def fold_pane(pane: WindowPane, running=None):
             # destination-bin the pane first (order-free folds only; no-op
             # otherwise): the round-robin strided slices of a sorted pane
             # stay sorted, so each partition's scatter is segment-local
@@ -1068,9 +1078,8 @@ class SummaryAggregation:
                     val = None if val is None else jax.tree.map(jnp.asarray, val)
                     mask = jnp.asarray(mask)
                 with tracing.span("gelly.fold.dispatch"):
-                    state = self._update_j(
-                        self.initial_state(cfg), src, dst, val, mask
-                    )
+                    base = self.initial_state(cfg) if running is None else running
+                    state = self._update_j(base, src, dst, val, mask)
                 partials.append(state)
             if not partials:
                 return None
@@ -1083,6 +1092,7 @@ class SummaryAggregation:
                 fold_pane,
                 checkpoint_path,
                 restore,
+                fold_into_running=into_running,
             )
 
         return OutputStream(records)
@@ -1151,8 +1161,11 @@ class SummaryAggregation:
     ) -> Iterator[tuple]:
         """Merger loop with the per-pane fold handed to the cohort consumer
         (see ``run_fused``).  Mirrors ``_merge_loop`` + the sync
-        ``fold_pane`` exactly; any drift here is a correctness bug, not a
-        style one."""
+        ``fold_pane``'s combine route (each pane folds from the initial
+        state: a cohort row cannot read its job's running summary); for an
+        order-free fold that route's records equal the sync plane's
+        fold-into-running ones.  Any drift here is a correctness bug, not
+        a style one."""
         cfg = stream.cfg
         window_ms = self.window_ms or cfg.window_ms
         running = None
@@ -1244,6 +1257,7 @@ class SummaryAggregation:
                     running = partial
                 else:
                     running = self._combine_j(running, partial)
+                metrics.pipeline_add("merge_combines", 1)
                 out = self.transform(running)
             # Emit BEFORE snapshotting: a crash between the two re-emits
             # this window on recovery (at-least-once emission) instead of
@@ -1583,6 +1597,7 @@ class SummaryAggregation:
         unwrap: bool = False,
         release: Optional[Callable] = None,
         fold_is_running: bool = False,
+        fold_into_running: bool = False,
     ) -> Iterator[tuple]:
         """The Merger: running merge + emission + positional checkpointing
         (SummaryAggregation.java:93-135), shared by the simulated and mesh
@@ -1592,6 +1607,11 @@ class SummaryAggregation:
         into PERSISTENT cross-window state and returns the running summary
         itself, so the loop skips the combine step — emission order,
         transient resets, and checkpoint semantics are unchanged.
+        ``fold_into_running`` (order-free single-partition folds, sync
+        only): the loop hands ``fold_pane(payload, running)`` the running
+        summary (None before the first window; the restored one after a
+        restore) and takes the result as the new running summary, again
+        with no combine.
 
         ``fold_pane(pane) -> summary | None`` supplies the per-pane partial
         fold+combine; everything downstream (merge order, transient reset,
@@ -1664,7 +1684,10 @@ class SummaryAggregation:
             # the window's spans (cut, put, fold, combine here; sink wait
             # and copy on the consumer's side) share its (job, window)
             tracing.enter_window(pane.window_id, span)
-            pane_summary = fold_pane(payload)
+            if fold_into_running:
+                pane_summary = fold_pane(payload, running)
+            else:
+                pane_summary = fold_pane(payload)
             tracing.end_cut()
             if pane_summary is None:
                 tracing.leave_window()
@@ -1672,10 +1695,15 @@ class SummaryAggregation:
             # Merger: non-blocking running merge, one emission per window
             # close (SummaryAggregation.java:107-119).
             with tracing.span("gelly.combine.dispatch"):
-                if running is None or self.transient_state or fold_is_running:
+                if fold_is_running or fold_into_running:
                     running = pane_summary
+                    metrics.pipeline_add("merge_running_folds", 1)
                 else:
-                    running = self._combine_j(running, pane_summary)
+                    if running is None or self.transient_state:
+                        running = pane_summary
+                    else:
+                        running = self._combine_j(running, pane_summary)
+                    metrics.pipeline_add("merge_combines", 1)
                 out = self.transform(running)
             # Emit BEFORE snapshotting: a crash between the two re-emits
             # this window on recovery (at-least-once emission) instead of

@@ -382,10 +382,15 @@ def async_merge_loop(
             pane_summary = fold_pane(payload)
             if pane_summary is None:
                 continue
-            if running is None or agg.transient_state or fold_is_running:
+            if fold_is_running:
                 running = pane_summary
+                metrics.pipeline_add("merge_running_folds", 1)
             else:
-                running = agg._combine_j(running, pane_summary)
+                if running is None or agg.transient_state:
+                    running = pane_summary
+                else:
+                    running = agg._combine_j(running, pane_summary)
+                metrics.pipeline_add("merge_combines", 1)
             out = agg.transform(running)
             rec = out if isinstance(out, tuple) else (out,)
             start_host_fetch(rec)

@@ -143,6 +143,10 @@ def _pipeline_zero() -> dict:
         "pipeline_prefetch_depth": 0,
         "pipeline_windows_dispatched": 0,
         "pipeline_windows_drained": 0,
+        # the Merger's route per pane: folded straight into the running
+        # summary, or folded from the initial state and merged by combine
+        "merge_running_folds": 0,
+        "merge_combines": 0,
     }
 
 
@@ -168,8 +172,10 @@ def pipeline_high_water(key: str, value: float) -> None:
 def pipeline_stats() -> dict:
     """Process-wide async-window pipeline occupancy counters: in-flight
     window high-water mark, per-stage stall seconds (pack / transfer /
-    dispatch / drain), prefetcher queue depth, and dispatched/drained window
-    counts.  Reported by bench.py next to ``compile_cache_stats``."""
+    dispatch / drain), prefetcher queue depth, dispatched/drained window
+    counts, and the Merger's panes by route (``merge_running_folds`` /
+    ``merge_combines``).  Reported by bench.py next to
+    ``compile_cache_stats``."""
     with _PIPE_LOCK:
         out = dict(_PIPELINE)
     out["pipeline_pack_stall_s"] = round(out["pipeline_pack_stall_s"], 4)
