@@ -197,14 +197,16 @@ def phase_served_cc(seed: int, capacity=CAPACITY, window_edges=WINDOW_EDGES,
 
 
 def _triangle_panes(seed: int, panes: int, window_ms: int):
-    """Per pane: edges among 4096 < k <= 8192 vertices drawn from a sparse
-    2^20 id space (so ``_pane_prepare`` compacts, then takes the dense
-    kernel), stamped inside the pane's window."""
+    """Per pane: edges among 4096 < k <= 8192 vertices with ids below the
+    dense kernel's bound (so ``_pane_prepare`` packs them for the dense
+    kernel as they are), stamped inside the pane's window."""
+    from gelly_streaming_tpu.ops.pallas_triangles import MAX_K
+
     rng = np.random.default_rng(seed)
     out = []
     for p in range(panes):
-        k = int(rng.integers(4097, 8193))
-        verts = rng.choice(1 << 20, size=k, replace=False).astype(np.int32)
+        k = int(rng.integers(4097, MAX_K + 1))
+        verts = rng.choice(MAX_K, size=k, replace=False).astype(np.int32)
         n = 1 << 17
         u = verts[rng.integers(0, k, n)]
         v = verts[rng.integers(0, k, n)]

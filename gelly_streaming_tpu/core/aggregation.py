@@ -1253,11 +1253,15 @@ class SummaryAggregation:
                 with tracing.span("gelly.fold.dispatch"):
                     partial = self._update_j(self.initial_state(cfg), *put)
             with tracing.span("gelly.combine.dispatch"):
-                if running is None or self.transient_state:
+                if self.transient_state:
                     running = partial
+                    metrics.pipeline_add("merge_transient_folds", 1)
                 else:
-                    running = self._combine_j(running, partial)
-                metrics.pipeline_add("merge_combines", 1)
+                    if running is None:
+                        running = partial
+                    else:
+                        running = self._combine_j(running, partial)
+                    metrics.pipeline_add("merge_combines", 1)
                 out = self.transform(running)
             # Emit BEFORE snapshotting: a crash between the two re-emits
             # this window on recovery (at-least-once emission) instead of
@@ -1698,8 +1702,12 @@ class SummaryAggregation:
                 if fold_is_running or fold_into_running:
                     running = pane_summary
                     metrics.pipeline_add("merge_running_folds", 1)
+                elif self.transient_state:
+                    # the pane alone is the window's summary: no combine
+                    running = pane_summary
+                    metrics.pipeline_add("merge_transient_folds", 1)
                 else:
-                    if running is None or self.transient_state:
+                    if running is None:
                         running = pane_summary
                     else:
                         running = self._combine_j(running, pane_summary)

@@ -385,8 +385,11 @@ def async_merge_loop(
             if fold_is_running:
                 running = pane_summary
                 metrics.pipeline_add("merge_running_folds", 1)
+            elif agg.transient_state:
+                running = pane_summary
+                metrics.pipeline_add("merge_transient_folds", 1)
             else:
-                if running is None or agg.transient_state:
+                if running is None:
                     running = pane_summary
                 else:
                     running = agg._combine_j(running, pane_summary)

@@ -3,11 +3,12 @@
 Window variant — reference example/WindowTriangles.java:50-65: slice(ALL) ->
 per-vertex candidate wedges (O(d^2), :82-115) -> keyBy(candidate edge) window
 join against real edges (:118-139) -> all-window sum.  The TPU-native
-re-design skips the wedge materialization entirely: per closed pane it builds
-the deduped undirected CSR and counts, for every canonical edge (u, v), the
-common neighbors |N(u) & N(v)| with one [E, D, D] masked equality reduction —
-each triangle is counted once per its three edges, so count = sum / 3.  Same
-result, no candidate shuffle.
+re-design never ships candidate wedges: a pane whose ids fit the dense MXU
+kernel (ops/pallas_triangles.py) counts as sum(A * (A @ A)) / 6; any other
+pane counts on the device by degree ordering (ops/oriented_triangles.py),
+whose work follows the oriented wedges, O(E * sqrt(E)) at worst, whatever
+the pane's skew.  ``WindowTriangleCount`` serves the same count per
+ingestion window as the ``triangles`` query.
 
 Streaming variant — reference example/ExactTriangleCount.java:43-56
 (KDD'16-style single pass): buildNeighborhood + canonical edges + stateful
@@ -34,8 +35,10 @@ from gelly_streaming_tpu.core.windows import (
     validate_slide,
     windowed_panes,
 )
+from gelly_streaming_tpu.core import compile_cache
+from gelly_streaming_tpu.core.aggregation import SummaryAggregation
 from gelly_streaming_tpu.ops import neighbors as nbr_ops
-from gelly_streaming_tpu.ops import pallas_triangles
+from gelly_streaming_tpu.ops import oriented_triangles, pallas_triangles
 
 
 # ---------------------------------------------------------------------------
@@ -43,10 +46,11 @@ from gelly_streaming_tpu.ops import pallas_triangles
 # ---------------------------------------------------------------------------
 
 
-# Panes whose compacted vertex count fits this bound use the dense MXU kernel
-# (ops/pallas_triangles.py) — the largest K it compiles for the chip; larger
-# panes take the padded-CSR path.  On the CPU the kernel runs in the Pallas
-# interpreter (slow), so there the dense path stays test-sized.
+# Panes whose ids (or, for a small pane, whose compacted vertices) fit this
+# bound use the dense MXU kernel (ops/pallas_triangles.py) — the largest K
+# it compiles for the chip; every other pane takes the degree-ordered sparse
+# count (ops/oriented_triangles.py).  On the CPU the dense kernel runs in the
+# Pallas interpreter (slow), so there the dense path stays test-sized.
 DENSE_PANE_MAX_VERTICES = pallas_triangles.MAX_K
 DENSE_PANE_MAX_VERTICES_INTERPRET = 512
 
@@ -59,43 +63,53 @@ def _dense_pane_bound() -> int:
     )
 
 
+def _pad_pane(src: np.ndarray, dst: np.ndarray):
+    """A pane's ids padded to the next power of two, with the mask of the
+    real entries: varying pane sizes share a bounded set of executables."""
+    n = len(src)
+    cap = max(1, 1 << (n - 1).bit_length())
+    s = np.zeros((cap,), np.int32)
+    d = np.zeros((cap,), np.int32)
+    mask = np.zeros((cap,), bool)
+    s[:n], d[:n], mask[:n] = src, dst, True
+    return s, d, mask
+
+
 def _pane_prepare(pane):
     """Host side of a pane submission: classify + pack, NO device calls.
 
     Returns ``(meta, host_arrays)`` fit for the prefetching pipeline
     (io/wire.py Prefetcher): the transfer thread device_puts
     ``host_arrays`` and ``_pane_dispatch`` turns the pair into an async
-    count handle.  Dense-eligible panes ship the 4 B/edge packed wire form
-    (ops/pallas_triangles.pack_pane); sparse id spaces are compacted here
-    (the host work overlaps the previous pane's transfer/compute)."""
+    count handle.  The path is picked by cheap host tests: ids below the
+    dense bound ship the 4 B/edge packed wire form
+    (ops/pallas_triangles.pack_pane); a pane too small to hold more
+    vertices than the bound is compacted and packed the same way; every
+    other pane ships raw, padded, for the device to count."""
     src, dst = pane
     if len(src) == 0:
         return ("const", 0), None
+    bound = _dense_pane_bound()
     max_id = int(max(src.max(), dst.max()))
-    if max_id < _dense_pane_bound():
+    if max_id < bound:
         # Ids already fit the dense kernel: ship packed words and let the
         # device scatter canonicalize/dedup (no host unique).
         w, n = pallas_triangles.pack_pane(
             src.astype(np.int32), dst.astype(np.int32)
         )
         return ("packed", max_id + 1), (w, n)
-    # Sparse id space: compact vertices on the host first.
-    lo = np.minimum(src, dst)
-    hi = np.maximum(src, dst)
-    keep = lo != hi
-    pairs = np.unique(np.stack([lo[keep], hi[keep]], axis=1), axis=0)
-    if len(pairs) == 0:
-        return ("const", 0), None
-    u, v = pairs[:, 0].astype(np.int32), pairs[:, 1].astype(np.int32)
-    verts, inv = np.unique(np.concatenate([u, v]), return_inverse=True)
-    cu, cv = inv[: len(u)].astype(np.int32), inv[len(u) :].astype(np.int32)
-    k_n = len(verts)
-    if k_n <= _dense_pane_bound():
-        w, n = pallas_triangles.pack_pane(cu, cv)
-        return ("packed", k_n), (w, n)
-    deg = np.bincount(np.concatenate([cu, cv]), minlength=k_n)
-    d_max = int(deg.max())
-    return ("csr", k_n, d_max), (cu, cv)
+    if 2 * len(src) <= bound:
+        verts, inv = np.unique(np.concatenate([src, dst]), return_inverse=True)
+        inv = inv.astype(np.int32)
+        w, n = pallas_triangles.pack_pane(inv[: len(src)], inv[len(src) :])
+        return ("packed", len(verts)), (w, n)
+    return ("sparse",), _pad_pane(src, dst)
+
+
+# the sparse pane count, shared process-wide: one executable per pow2 size
+_sparse_count = compile_cache.cached_jit(
+    ("oriented_triangles",), lambda: oriented_triangles.count_stats
+)
 
 
 def _pane_dispatch(meta, arrays):
@@ -108,9 +122,8 @@ def _pane_dispatch(meta, arrays):
             "halves",
             pallas_triangles.pane_triangles_submit_packed(w, n, meta[1]),
         )
-    _, k_n, d_max = meta
-    cu, cv = arrays
-    return ("scalar", _count_kernel(jnp.asarray(cu), jnp.asarray(cv), k_n, d_max))
+    src, dst, mask = arrays
+    return ("stats", _sparse_count(src, dst, mask))
 
 
 def _pane_triangle_submit(src: np.ndarray, dst: np.ndarray):
@@ -131,7 +144,7 @@ def _pane_triangle_finish(handle) -> int:
         return payload
     if kind == "halves":
         return pallas_triangles.triangles_from_halves(payload)
-    return int(payload)
+    return oriented_triangles.limbs(np.asarray(payload), "triangles")
 
 
 def _pane_triangle_count(src: np.ndarray, dst: np.ndarray) -> int:
@@ -217,61 +230,14 @@ def pipelined_pane_counts(
     return counts
 
 
-from gelly_streaming_tpu.core import compile_cache
-
-
-def _superpane_count_fn(k: int, e_pad: int, num_vertices: int, max_deg: int):
-    """Compiled K-pane triangle counter: one vmapped masked-CSR dispatch
-    over ``k`` panes' canonical edges (padded to shared static shapes) —
-    the superbatch form of the per-pane ``_count_kernel`` dispatch.  Exact:
-    per pane it is the same |N(u) & N(v)| equality reduction, with padding
-    rows masked out of both the insert and the reduction."""
-    from gelly_streaming_tpu.core import compile_cache
-
-    def make():
-        def one(u, v, ok):
-            table = nbr_ops.init_table(num_vertices, max_deg)
-            both_src = jnp.concatenate([u, v])
-            both_dst = jnp.concatenate([v, u])
-            table = nbr_ops.insert_batch(
-                table, both_src, both_dst, jnp.concatenate([ok, ok])
-            )
-            rows_u, valid_u = nbr_ops.gather_rows(table, u)
-            rows_v, valid_v = nbr_ops.gather_rows(table, v)
-            eq = (
-                (rows_u[:, :, None] == rows_v[:, None, :])
-                & valid_u[:, :, None]
-                & valid_v[:, None, :]
-                & ok[:, None, None]
-            )
-            return jnp.sum(eq.astype(jnp.int32)) // 3
-
-        return jax.vmap(one)
-
+def _superpane_count_fn(k: int, e_pad: int):
+    """Compiled K-pane triangle counter: the sparse pane count vmapped over
+    ``k`` panes' edges (padded to one shared static shape) — the superbatch
+    form of the per-pane dispatch, exact per pane (padding is masked)."""
     return compile_cache.cached_jit(
-        ("superpane_triangles", k, e_pad, num_vertices, max_deg), make
+        ("superpane_triangles", k, e_pad),
+        lambda: jax.vmap(oriented_triangles.count_stats),
     )
-
-
-def _superpane_canonical(pane_edges):
-    """Canonicalize one pane's edges for the masked-CSR counter: dedup'd
-    undirected (lo, hi) pairs, self-loops dropped, ids COMPACTED to the
-    pane's vertex set (the same host prep as _pane_prepare's CSR path)."""
-    src, dst = pane_edges
-    if len(src) == 0:
-        return None
-    lo = np.minimum(src, dst)
-    hi = np.maximum(src, dst)
-    keep = lo != hi
-    pairs = np.unique(np.stack([lo[keep], hi[keep]], axis=1), axis=0)
-    if len(pairs) == 0:
-        return None
-    u, v = pairs[:, 0].astype(np.int32), pairs[:, 1].astype(np.int32)
-    verts, inv = np.unique(np.concatenate([u, v]), return_inverse=True)
-    cu = inv[: len(u)].astype(np.int32)
-    cv = inv[len(u) :].astype(np.int32)
-    deg = np.bincount(np.concatenate([cu, cv]), minlength=len(verts))
-    return cu, cv, len(verts), int(deg.max())
 
 
 def _superbatched_window_counts(panes, k: int):
@@ -286,13 +252,10 @@ def _superbatched_window_counts(panes, k: int):
     # keep_empty: this consumer emits (0, max_timestamp) even for panes
     # with no edges, exactly as the per-pane dispatch path does
     for group in group_panes(iter(panes), k, keep_empty=True):
-        prepped = [_superpane_canonical((p.src, p.dst)) for p in group]
-        live = [i for i, pr in enumerate(prepped) if pr is not None]
+        live = [i for i, p in enumerate(group) if len(p.src)]
         counts = [0] * len(group)
         if live:
-            e_pad = max(1, 1 << (max(len(prepped[i][0]) for i in live) - 1).bit_length())
-            n_v = max(1, 1 << (max(prepped[i][2] for i in live) - 1).bit_length())
-            d_max = max(1, 1 << (max(prepped[i][3] for i in live) - 1).bit_length())
+            e_pad = max(1, 1 << (max(len(group[i].src) for i in live) - 1).bit_length())
             # pow2 row bucket (matching the docstring + the aggregation
             # path): varying group occupancy must not mint new compiled
             # variants per count — extra rows are fully masked, count 0
@@ -301,42 +264,16 @@ def _superbatched_window_counts(panes, k: int):
             v = np.zeros((kk, e_pad), np.int32)
             ok = np.zeros((kk, e_pad), bool)
             for row, i in enumerate(live):
-                cu, cv, _, _ = prepped[i]
-                u[row, : len(cu)] = cu
-                v[row, : len(cv)] = cv
-                ok[row, : len(cu)] = True
-            fn = _superpane_count_fn(kk, e_pad, n_v, d_max)
+                n = len(group[i].src)
+                u[row, :n] = group[i].src
+                v[row, :n] = group[i].dst
+                ok[row, :n] = True
+            fn = _superpane_count_fn(kk, e_pad)
             out = np.asarray(fn(jnp.asarray(u), jnp.asarray(v), jnp.asarray(ok)))
             for row, i in enumerate(live):
-                counts[i] = int(out[row])
+                counts[i] = oriented_triangles.limbs(out[row], "triangles")
         for i, pane in enumerate(group):
             yield counts[i], pane.max_timestamp
-
-
-def _count_kernel_impl(u: jax.Array, v: jax.Array, num_vertices: int, max_deg: int):
-    """sum over edges |N(u) & N(v)| / 3 with a padded-CSR equality reduction."""
-    e = u.shape[0]
-    table = nbr_ops.init_table(num_vertices, max_deg)
-    both_src = jnp.concatenate([u, v])
-    both_dst = jnp.concatenate([v, u])
-    table = nbr_ops.insert_batch(
-        table, both_src, both_dst, jnp.ones((2 * e,), bool)
-    )
-    rows_u, valid_u = nbr_ops.gather_rows(table, u)  # [E, D]
-    rows_v, valid_v = nbr_ops.gather_rows(table, v)
-    eq = (
-        (rows_u[:, :, None] == rows_v[:, None, :])
-        & valid_u[:, :, None]
-        & valid_v[:, None, :]
-    )
-    return jnp.sum(eq.astype(jnp.int32)) // 3
-
-
-# shared executable for the per-pane count: (num_vertices, max_deg) are
-# pow2-bucketed by the caller, so each bucket compiles once process-wide
-_count_kernel = compile_cache.cached_jit(
-    ("tri_count_kernel",), lambda: _count_kernel_impl, static_argnums=(2, 3)
-)
 
 
 def window_triangles(
@@ -417,6 +354,74 @@ def window_triangles(
             yield (_pane_triangle_finish(pending[0]), pending[1])
 
     return OutputStream(records)
+
+
+class WindowTriangles:
+    """One window's record of the served ``triangles`` query.
+
+    ``stats`` is the window's ``oriented_triangles.STATS`` vector, on the
+    device until the sink copies it.  What travels is the count alone, as
+    two int32 limbs (``count = low + (high << 30)``, exact past 2^31); the
+    rest are the window's kernel counters, which come to the host in the
+    same copy and tag its span."""
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    @property
+    def count(self) -> int:
+        return oriented_triangles.limbs(np.asarray(self.stats), "triangles")
+
+    def on_host(self, arrays):
+        """``record_leaves``' hook once the copy is on the host: the leaves
+        that travel, and the counters (added to ``pipeline_stats``)."""
+        from gelly_streaming_tpu.utils import metrics
+
+        (stats,) = arrays
+        counters = oriented_triangles.counters(stats)
+        metrics.pipeline_add("tri_windows", 1)
+        for key in ("tri_vertices", "tri_edges", "tri_wedges", "tri_slots"):
+            metrics.pipeline_add(key, counters[key])
+        metrics.pipeline_high_water("tri_bucket_high_water", counters["tri_bucket"])
+        return [stats[:2].copy()], counters
+
+
+class WindowTriangleCount(SummaryAggregation):
+    """The served ``triangles`` query: the exact triangle count of each
+    ingestion window's simple undirected graph (self loops dropped, repeats
+    merged, direction ignored) — the reference's WindowTriangles
+    (example/WindowTriangles.java:50-65) per window, one ``WindowTriangles``
+    record each.
+
+    A window's triangles are no fold over its micro-batches, and the counts
+    of two partitions do not add up: the state is transient (reset after
+    every emission), the windowed plane hands each closed pane to ONE
+    ``update`` from the initial state (the ``jit_gelly_fold`` executable,
+    which is the whole device count), and ``combine`` refuses."""
+
+    transient_state = True
+
+    @property
+    def cache_token(self):
+        return type(self)
+
+    def initial_state(self, cfg: StreamConfig):
+        return jnp.zeros((len(oriented_triangles.STATS),), jnp.int32)
+
+    def update(self, state, src, dst, val, mask):
+        return state + oriented_triangles.count_stats(src, dst, mask)
+
+    def combine(self, a, b):
+        raise NotImplementedError(
+            "a window's triangle count does not combine across partitions"
+        )
+
+    def transform(self, state):
+        return WindowTriangles(state)
+
+    def _wire_eligible(self, stream) -> bool:
+        # the wire fast path folds batch by batch into one carry
+        return False
 
 
 # ---------------------------------------------------------------------------

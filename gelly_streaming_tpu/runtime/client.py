@@ -532,7 +532,7 @@ def main(argv=None) -> int:
     p_submit = sub.add_parser("submit", help="submit a push-source job")
     p_submit.add_argument("--name", required=True)
     p_submit.add_argument(
-        "--query", default="cc", choices=("cc", "degree", "edges")
+        "--query", default="cc", choices=("cc", "degree", "edges", "triangles")
     )
     p_submit.add_argument(
         "--summary",

@@ -152,6 +152,7 @@ def main(argv=None) -> int:
             "cc",
             "degree",
             "edges",
+            "triangles",
             "sketch_triangles",
             "hll_degree",
             "cm_heavy_hitters",

@@ -118,9 +118,10 @@ def _edge_count_descriptor():
 
 def descriptor_for(query: str, spec: Optional[dict] = None):
     """The serving plane's query catalog (shared with ``gelly-serve``'s
-    synthetic driver): the exact summaries ``cc`` / ``degree`` / ``edges``
-    plus the fixed-tiny-state sketch family (``sketch_triangles`` /
-    ``hll_degree`` / ``cm_heavy_hitters``).
+    synthetic driver): the exact summaries ``cc`` / ``degree`` / ``edges``,
+    the exact per-window ``triangles`` count (one record per window, reset
+    after each) plus the fixed-tiny-state sketch family
+    (``sketch_triangles`` / ``hll_degree`` / ``cm_heavy_hitters``).
 
     Sketch kinds read their accuracy knobs — ``eps`` / ``delta`` (and
     ``top_k`` for the heavy-hitter sketch) — from ``spec``; malformed
@@ -141,6 +142,13 @@ def descriptor_for(query: str, spec: Optional[dict] = None):
         return DegreeDistributionSummary()
     if query == "edges":
         return _edge_count_descriptor()
+    if query == "triangles":
+        from gelly_streaming_tpu.library.triangles import WindowTriangleCount
+
+        if int((spec or {}).get("num_shards", 1)) > 1:
+            # a window's triangles do not add up across partitions
+            raise _Refused("bad-spec", "triangles counts each window on one chip")
+        return WindowTriangleCount()
     from gelly_streaming_tpu.library import sketches
 
     if query in sketches.SKETCH_KINDS:
@@ -161,8 +169,8 @@ def descriptor_for(query: str, spec: Optional[dict] = None):
             raise _Refused("bad-spec", str(e))
     raise _Refused(
         "bad-spec",
-        f"unknown query {query!r} (expected cc/degree/edges or a sketch "
-        f"kind: {'/'.join(sketches.SKETCH_KINDS)})",
+        f"unknown query {query!r} (expected cc/degree/edges/triangles or "
+        f"a sketch kind: {'/'.join(sketches.SKETCH_KINDS)})",
     )
 
 
@@ -179,40 +187,55 @@ def record_leaves(rec) -> list:
     opaque — their array attributes are what travels.  Anything that would
     land as a pickled object array is refused loudly instead (the wire
     carries arrays, never code).
+
+    A record object with an ``on_host(arrays)`` method (the window
+    triangle count's) copies more than travels: its leaves come to the
+    host in the same copy, and the method returns the leaves that travel
+    and the window's counters, which tag the copy's span.
     """
     import jax
 
     out: list = []
-    _collect_leaves(rec, out, jax.Array)
+    split: list = []
+    _collect_leaves(rec, out, jax.Array, split)
     # the wait for the device to compute the leaves, apart from their copy
     # to host: a long wait is the device still busy, a long copy the host
     # moving bytes while the device may sit idle
     with tracing.span("gelly.sink.wait"):
         jax.block_until_ready([x for x in out if isinstance(x, jax.Array)])
-    with tracing.span("gelly.sink.copy"):
-        return [np.asarray(x) for x in out]
+    with tracing.span("gelly.sink.copy") as copy:
+        host = [np.asarray(x) for x in out]
+        for start, stop, owner in reversed(split):
+            leaves, counters = owner.on_host(host[start:stop])
+            host[start:stop] = leaves
+            copy.tag(**counters)
+        return host
 
 
-def _collect_leaves(x, out: list, device_array) -> None:
+def _collect_leaves(x, out: list, device_array, split: list) -> None:
     """``record_leaves``' walk, appending leaves to ``out`` as they are
-    (device arrays stay on the device).  A module-level function, not a
-    closure: a recursive closure is a reference cycle, which would keep
-    every record's device leaves alive until the garbage collector ran."""
+    (device arrays stay on the device), and to ``split`` the leaf range
+    of each object with an ``on_host`` method.  A module-level function,
+    not a closure: a recursive closure is a reference cycle, which would
+    keep every record's device leaves alive until the collector ran."""
     if isinstance(x, (tuple, list)):
         for item in x:
-            _collect_leaves(item, out, device_array)
+            _collect_leaves(item, out, device_array, split)
         return
     if isinstance(x, dict):
         for key in sorted(x):
-            _collect_leaves(x[key], out, device_array)
+            _collect_leaves(x[key], out, device_array, split)
         return
     if isinstance(x, (np.ndarray, np.generic, int, float, bool, device_array)):
         out.append(x)
         return
     state = getattr(x, "__dict__", None)
     if state:
+        start = len(out)
         for key in sorted(state):
-            _collect_leaves(state[key], out, device_array)
+            _collect_leaves(state[key], out, device_array, split)
+        if hasattr(x, "on_host"):
+            split.append((start, len(out), x))
         return
     arr = np.asarray(x)
     if arr.dtype == object:

@@ -147,6 +147,18 @@ def _pipeline_zero() -> dict:
         # summary, or folded from the initial state and merged by combine
         "merge_running_folds": 0,
         "merge_combines": 0,
+        # transient descriptors: a pane folded from the initial state and
+        # emitted alone, with no combine
+        "merge_transient_folds": 0,
+        # the served triangles query's kernel counters, summed per window
+        # (ops/oriented_triangles.counters), and the largest out-degree
+        # bucket a window needed
+        "tri_windows": 0,
+        "tri_vertices": 0,
+        "tri_edges": 0,
+        "tri_wedges": 0,
+        "tri_slots": 0,
+        "tri_bucket_high_water": 0,
     }
 
 
@@ -173,9 +185,10 @@ def pipeline_stats() -> dict:
     """Process-wide async-window pipeline occupancy counters: in-flight
     window high-water mark, per-stage stall seconds (pack / transfer /
     dispatch / drain), prefetcher queue depth, dispatched/drained window
-    counts, and the Merger's panes by route (``merge_running_folds`` /
-    ``merge_combines``).  Reported by bench.py next to
-    ``compile_cache_stats``."""
+    counts, the Merger's panes by route (``merge_running_folds`` /
+    ``merge_combines`` / ``merge_transient_folds``) and the served triangle
+    count's per-window kernel counters (``tri_*``).  Reported by bench.py
+    next to ``compile_cache_stats``."""
     with _PIPE_LOCK:
         out = dict(_PIPELINE)
     out["pipeline_pack_stall_s"] = round(out["pipeline_pack_stall_s"], 4)

@@ -51,3 +51,18 @@ def host_min_labels(capacity, src, dst):
         if ra != rb:
             parent[max(ra, rb)] = min(ra, rb)
     return np.array([find(v) for v in range(capacity)])
+
+
+def kronecker(scale: int, edges: int, seed: int):
+    """Graph 500 Kronecker edges (A/B/C = 0.57/0.19/0.19), labels permuted."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    src = np.zeros(edges, np.int64)
+    dst = np.zeros(edges, np.int64)
+    for level in range(scale):
+        u = rng.random(edges)
+        src |= (u >= 0.76).astype(np.int64) << level  # quadrants C and D
+        dst |= (((u >= 0.57) & (u < 0.76)) | (u >= 0.95)).astype(np.int64) << level
+    perm = rng.permutation(1 << scale)
+    return perm[src].astype(np.int32), perm[dst].astype(np.int32)

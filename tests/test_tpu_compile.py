@@ -150,3 +150,23 @@ def test_sharded_cc_step_compiles_on_four_chips(topo):
     assert "all-to-all" in text  # the delta exchange crosses chips
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_triangle_count_compiles_as_the_served_fold(one_chip):
+    """The served ``triangles`` fold (ops/oriented_triangles.py through
+    ``WindowTriangleCount``'s ``jit_gelly_fold``): sorts, scatters, gathers
+    and the wedge loop's dynamic trip counts lower for the chip.  Compiled
+    at 2^12 entries: at the served 2^21 the TPU's sorts take ~2 min of host
+    compile time (PERF.md §7), too long for this suite."""
+    from gelly_streaming_tpu.library.triangles import WindowTriangleCount
+    from gelly_streaming_tpu.ops import oriented_triangles as ot
+
+    desc = WindowTriangleCount()
+    n = 1 << 12
+    state = _shape((len(ot.STATS),), jnp.int32, one_chip)
+    ids = _shape((n,), jnp.int32, one_chip)
+    mask = _shape((n,), jnp.bool_, one_chip)
+    lowered = jax.jit(desc.update).lower(state, ids, ids, None, mask)
+    assert "while" in lowered.as_text()
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES

@@ -121,16 +121,22 @@ def test_pipelined_pane_counts_match_sequential():
     )
     from gelly_streaming_tpu.utils.metrics import WindowLatencyRecorder
 
+    import triangle_reference
+
     rng = np.random.default_rng(3)
+    # small ids take the dense kernel; 200 vertices drawn from a 2^20 id
+    # space, with too many edges to compact on the host, take the
+    # degree-ordered sparse count
+    ids = [np.arange(64), rng.choice(1 << 20, 200, replace=False)]
     panes = [
         (
-            rng.integers(0, 64, 300).astype(np.int32),
-            rng.integers(0, 64, 300).astype(np.int32),
+            ids[sparse][rng.integers(0, len(ids[sparse]), n)].astype(np.int32),
+            ids[sparse][rng.integers(0, len(ids[sparse]), n)].astype(np.int32),
         )
-        for _ in range(5)
+        for sparse, n in [(0, 300)] * 5 + [(1, 2000), (0, 4000), (1, 900)]
     ] + [(np.zeros(0, np.int32), np.zeros(0, np.int32))]
     rec = WindowLatencyRecorder()
     piped = pipelined_pane_counts(panes, recorder=rec, warmup=1)
     seq = [_pane_triangle_count(s, d) for s, d in panes]
-    assert piped == seq
+    assert piped == seq == [triangle_reference.count(s, d) for s, d in panes]
     assert len(rec.latencies_ms) == len(panes) - 1  # warmup pane unrecorded
