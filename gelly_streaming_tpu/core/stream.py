@@ -670,7 +670,15 @@ class EdgeStream:
         return compile_cache.cached_jit(("pipeline_step", stages), build)
 
     def batches(self) -> Iterator[EdgeBatch]:
-        """Run the pipeline, yielding transformed micro-batches."""
+        """Run the pipeline, yielding transformed micro-batches.
+
+        A stage-less chain yields the source's own batches: its step would
+        be the identity, and a dispatched copy that a host consumer (the
+        pane cutter, core/windows.py) reads back waits behind whatever the
+        device is running."""
+        if not self._stages:
+            yield from self._source_factory()
+            return
         states = tuple(stage.init(self.cfg) for stage in self._stages)
         step = self._compiled_step()
         for batch in self._source_factory():

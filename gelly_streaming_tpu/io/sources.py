@@ -668,9 +668,10 @@ class NetworkEdgeSource:
                 # copied out BEFORE the arena rejoins the pool's free-list
                 s, d = np.array(s), np.array(d)
                 release()
-            # host-array batches: the pane cutter consumes numpy directly,
-            # so the per-batch jnp round trip (the measured ceiling of
-            # this path — ISSUE 14) never happens
+            # host-array batches: the served stream has no stages, so the
+            # pane cutter reads these numpy leaves as they are
+            # (EdgeStream.batches): no jnp conversion, no pipeline step,
+            # no device copy read back behind the running fold
             yield EdgeBatch.from_host_arrays(s, d, pad_to=self.batch)
 
 

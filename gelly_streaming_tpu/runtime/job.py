@@ -44,10 +44,11 @@ statically (tests/analysis_corpus/{good,bad}_jobstate.py).
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
-from typing import Any, Callable, Iterator, List, Optional
+from typing import Any, Callable, Deque, Iterator, List, Optional
 
 from gelly_streaming_tpu.utils import events
 
@@ -193,6 +194,9 @@ class Job:
         # the window of the last record pulled (its emission's window id):
         # the window being filled next is this + 1
         self._last_window = -1  # single-thread: scheduler
+        # emitted records whose device work may still be running, oldest
+        # first (the scheduler's in-flight bound, runtime/manager.py)
+        self._unfinished: Deque = collections.deque()  # single-thread: scheduler
         self._trace_dump: Optional[List[dict]] = None  # guarded-by: _lock
 
     # -- read-side API -------------------------------------------------------

@@ -21,6 +21,10 @@ Isolation boundaries:
   ``put_nowait``s; a job whose sink lags until its queue fills is simply
   skipped for the round (``job_queue_full_skips`` counts it) while other
   jobs keep dispatching.  A slow sink slows ITS job, nothing else.
+* **Per-job bounded device work**: a job with ``_DEVICE_AHEAD`` emitted
+  records still computing on the device is skipped for the round
+  (``job_device_wait_skips``), so asynchronous dispatch cannot stack a
+  job's emission queue of summaries on the device ahead of their folds.
 * **Per-job checkpoints**: each job snapshots its own position/summary
   through the unchanged ``utils/checkpoint.py`` machinery, so jobs
   crash-resume independently.
@@ -64,6 +68,44 @@ from gelly_streaming_tpu.utils import events, metrics, tracing
 # with this fused partial" (which may legitimately be None — the solo
 # fallback) in the scheduler's pull loop
 _FRESH = object()
+
+# A job's emitted records whose device work may still be running: while
+# this many are unfinished, a fresh pull waits (the job is skipped for the
+# round, ``job_device_wait_skips``).  Dispatch is asynchronous, so without
+# it a backlogged job stacks a whole emission queue of summaries on the
+# device ahead of the folds that compute them, past what admission
+# charged it.  Two keeps the cut of window w+1 overlapping fold(w).
+_DEVICE_AHEAD = 2
+
+
+def _device_leaves(rec, depth: int = 2) -> list:
+    """The device arrays in ``rec``: its pytree leaves, and those among the
+    attributes of host wrapper objects (a summary record such as connected
+    components' ``DisjointSet`` is not a pytree), ``depth`` objects deep."""
+    import jax
+
+    out = []
+    for leaf in jax.tree_util.tree_leaves(rec):
+        if isinstance(leaf, jax.Array):
+            out.append(leaf)
+        elif depth and isinstance(getattr(leaf, "__dict__", None), dict):
+            out += _device_leaves(vars(leaf), depth - 1)
+    return out
+
+
+def _computed(rec) -> bool:
+    """True once every device array in ``rec`` is computed; never blocks.
+    A deleted array holds no buffer (and is_ready() must not see it)."""
+    return all(x.is_deleted() or x.is_ready() for x in _device_leaves(rec))
+
+
+def _device_behind(job: Job) -> bool:  # single-thread: scheduler
+    """Drop the job's computed records from its unfinished list; True while
+    ``_DEVICE_AHEAD`` of them are still running on the device."""
+    unfinished = job._unfinished
+    while unfinished and _computed(unfinished[0]):
+        unfinished.popleft()
+    return len(unfinished) >= _DEVICE_AHEAD
 
 
 class _Quantum:
@@ -844,9 +886,11 @@ class JobManager:
                     job._transition(JobState.RUNNING)
                 elif not job._state_in(JobState.RUNNING):
                     # PAUSED / DRAINING / terminal: only a deferred
-                    # sentinel still needs delivering
+                    # sentinel still needs delivering (and the computed
+                    # records' references dropping)
                     if sentinel_owed:
                         self._enqueue_sentinel(job)
+                    _device_behind(job)
                     return False
         if cancel_now:
             self._cancel_now(job)
@@ -909,6 +953,11 @@ class JobManager:
                         break
                     if job._out.full():
                         metrics.job_add(job.job_id, "job_queue_full_skips", 1)
+                        break
+                    if _device_behind(job):
+                        metrics.job_add(
+                            job.job_id, "job_device_wait_skips", 1
+                        )
                         break
                     if q.pulled and ready is not None and not ready():
                         # re-check between pulls: each pull drains a
@@ -990,6 +1039,7 @@ class JobManager:
                     if em is not None:
                         tracing.finish(em, em.t_emit)
                     job._out.put_nowait(rec)
+                job._unfinished.append(rec)
                 metrics.job_high_water(
                     job.job_id, "job_queue_depth_hwm", job._out.qsize()
                 )

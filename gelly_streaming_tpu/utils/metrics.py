@@ -407,6 +407,9 @@ def _job_zero() -> dict:
         # window queued (the network-ingest isolation boundary: a slow or
         # dead client idles ITS job, never the scheduler round)
         "job_source_wait_skips": 0,
+        # rounds the job was skipped because its last emitted records were
+        # still computing on the device (the scheduler's in-flight bound)
+        "job_device_wait_skips": 0,
         # deepest output-queue occupancy seen (sink lag indicator)
         "job_queue_depth_hwm": 0,
     }

@@ -383,3 +383,141 @@ def test_ingest_panes_fast_path_covers_replay_source():
         .collect()
     )
     assert out[-1][0].components() == plain[-1][0].components()
+
+
+# ---------------------------------------------------------------------------
+# the pane cutter reads a stage-less source's own batches (no pipeline step)
+# ---------------------------------------------------------------------------
+
+
+def _host_batches(src, dst, batch, time=None):
+    """Numpy-leaf batches, the network source's kind; the last one padded."""
+
+    def factory():
+        for i in range(0, len(src), batch):
+            if time is None:
+                yield EdgeBatch.from_host_arrays(
+                    src[i : i + batch], dst[i : i + batch], pad_to=batch
+                )
+                continue
+            n = len(src[i : i + batch])
+            pad = np.zeros(batch - n, np.int32)
+            yield EdgeBatch(
+                src=np.concatenate([src[i : i + batch], pad]),
+                dst=np.concatenate([dst[i : i + batch], pad]),
+                mask=np.arange(batch) < n,
+                time=np.concatenate([time[i : i + batch], pad]),
+            )
+
+    return factory
+
+
+def _device_batches(src, dst, batch, time=None):
+    def factory():
+        for i in range(0, len(src), batch):
+            yield EdgeBatch.from_arrays(
+                src[i : i + batch],
+                dst[i : i + batch],
+                time=None if time is None else time[i : i + batch],
+                pad_to=batch,
+            )
+
+    return factory
+
+
+@pytest.mark.parametrize(
+    "leaves, every_edges",
+    [
+        ("host", 64),  # window = two batches, padded last batch
+        ("host", 40),  # cuts land mid-batch
+        ("device", 64),
+        ("host", 0),  # timed: event-time tumbling panes
+        ("device", 0),
+    ],
+)
+def test_pane_batches_cut_the_same_panes(leaves, every_edges):
+    """A stage-less stream's panes, cut from the source's own batches, are
+    the panes its compiled identity step would have given the cutter: the
+    same window ids, edges and order, host or device leaves, count or
+    event-time cuts."""
+    from gelly_streaming_tpu.core.windows import (
+        assign_tumbling_windows,
+        stream_panes,
+    )
+
+    rng = np.random.default_rng(47)
+    n, batch = 200, 32
+    src = rng.integers(0, 64, n).astype(np.int32)
+    dst = rng.integers(0, 64, n).astype(np.int32)
+    time = None if every_edges else np.sort(rng.integers(0, 900, n)).astype(np.int32)
+    factory = (_host_batches if leaves == "host" else _device_batches)(
+        src, dst, batch, time
+    )
+    cfg = StreamConfig(
+        vertex_capacity=64, batch_size=batch, ingest_window_edges=every_edges
+    )
+    stream = EdgeStream.from_batches(factory, cfg)
+    got = list(stream_panes(stream, 100))
+    step = stream._compiled_step()
+    stepped = (step((), b)[1] for b in factory())
+    if every_edges:
+        want = list(assign_ingestion_windows(stepped, every_edges))
+    else:
+        want = list(assign_tumbling_windows(stepped, 100))
+    assert len(got) == len(want) > 2
+    for g, w in zip(got, want):
+        assert (g.window_id, g.max_timestamp) == (w.window_id, w.max_timestamp)
+        np.testing.assert_array_equal(g.src, w.src)
+        np.testing.assert_array_equal(g.dst, w.dst)
+        assert (g.time is None) == (w.time is None)
+        if g.time is not None:
+            np.testing.assert_array_equal(g.time, w.time)
+    assert np.concatenate([p.src for p in got]).tolist() == src.tolist()
+
+
+def test_stage_less_panes_run_no_pipeline_step(monkeypatch):
+    """A stage-less stream never touches the compiled pipeline step:
+    ``batches()`` yields the source's own batch objects and the panes are
+    cut from them."""
+    from gelly_streaming_tpu.core.windows import stream_panes
+
+    def no_step(self):
+        raise AssertionError("a stage-less stream ran the pipeline step")
+
+    monkeypatch.setattr(EdgeStream, "_compiled_step", no_step)
+    rng = np.random.default_rng(53)
+    src = rng.integers(0, 64, 160).astype(np.int32)
+    dst = rng.integers(0, 64, 160).astype(np.int32)
+    cfg = StreamConfig(vertex_capacity=64, batch_size=32, ingest_window_edges=48)
+    factory = _host_batches(src, dst, 32)
+    stream = EdgeStream.from_batches(factory, cfg)
+    for own, got in zip(factory(), stream.batches()):
+        assert type(got.src) is np.ndarray
+        np.testing.assert_array_equal(got.src, own.src)
+    panes = list(stream_panes(stream, 0))
+    assert [p.num_edges for p in panes] == [48, 48, 48, 16]
+    assert np.concatenate([p.dst for p in panes]).tolist() == dst.tolist()
+
+
+def test_staged_panes_still_step(monkeypatch):
+    """A staged stream's panes come through its compiled step and hold what
+    the stage made of the edges."""
+    from gelly_streaming_tpu.core.windows import stream_panes
+
+    steps = []
+    orig = EdgeStream._compiled_step
+
+    def spy(self):
+        steps.append(self._stages)
+        return orig(self)
+
+    monkeypatch.setattr(EdgeStream, "_compiled_step", spy)
+    rng = np.random.default_rng(59)
+    src = rng.integers(0, 64, 160).astype(np.int32)
+    dst = rng.integers(0, 64, 160).astype(np.int32)
+    cfg = StreamConfig(vertex_capacity=64, batch_size=32, ingest_window_edges=48)
+    stream = EdgeStream.from_batches(_host_batches(src, dst, 32), cfg).reverse()
+    panes = list(stream_panes(stream, 0))
+    assert len(steps) == 1 and len(steps[0]) == 1
+    assert np.concatenate([p.src for p in panes]).tolist() == dst.tolist()
+    assert np.concatenate([p.dst for p in panes]).tolist() == src.tolist()

@@ -19,6 +19,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -692,6 +693,66 @@ def test_one_job_failure_is_isolated():
 
         with pytest.raises(JobError, match="query exploded"):
             bad.collect()
+
+
+def _wait_for(cond, what, timeout_s=30.0):
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.01)
+
+
+def test_job_runs_at_most_two_records_ahead_of_the_device(monkeypatch):
+    """Dispatch is asynchronous, so the scheduler bounds each job's records
+    still computing on the device: with none finished a job emits two and
+    is then skipped (``job_device_wait_skips``); each record that finishes
+    lets exactly one more pull run, and every record arrives in order."""
+    from gelly_streaming_tpu.runtime import manager as mgr
+
+    done = set()
+    monkeypatch.setattr(mgr, "_computed", lambda rec: rec[0] in done)
+
+    def records():
+        for i in range(8):
+            yield (i,)
+
+    with JobManager() as jm:
+        job = jm.submit(records, name="ahead")
+
+        def skips():
+            return jm.status()["jobs"]["ahead"]["job_device_wait_skips"]
+
+        _wait_for(lambda: skips() >= 3, "the device-wait skips")
+        assert job.queue_depth == mgr._DEVICE_AHEAD == 2
+        for i in range(3):
+            done.add(i)
+            _wait_for(lambda: job.queue_depth == 3 + i, f"record {i + 2}")
+            before = skips()
+            _wait_for(lambda: skips() >= before + 3, "the bound to hold again")
+            assert job.queue_depth == 3 + i
+        done.update(range(8))
+        assert job.collect() == [(i,) for i in range(8)]
+        assert job.state == JobState.DONE
+
+
+def test_computed_reads_device_readiness():
+    """The bound's readiness probe finds the device arrays inside pytrees
+    and inside host wrapper objects (a summary record such as
+    ``DisjointSet`` is not a pytree); computed and deleted arrays and host
+    leaves count as finished (a deleted array is never probed)."""
+    from gelly_streaming_tpu.runtime.manager import _computed, _device_leaves
+
+    class Summary:
+        def __init__(self, **attrs):
+            self.__dict__.update(attrs)
+
+    parent, seen = jnp.arange(4), jnp.ones(3)
+    rec = (7, Summary(parent=parent, extra={"seen": seen, "n": 3}), np.zeros(2), None)
+    assert {id(x) for x in _device_leaves(rec)} == {id(parent), id(seen)}
+    gone = jnp.arange(4)
+    gone.delete()
+    assert _computed(rec)
+    assert _computed({"summary": gone, "wrapped": Summary(parent=parent)})
 
 
 # ---------------------------------------------------------------------------

@@ -225,6 +225,55 @@ def test_warmed_same_shape_remote_jobs_compile_nothing():
     )
 
 
+def test_served_cc_cuts_panes_without_pipeline_step(monkeypatch):
+    """A pushed cc job's pane cutter reads the network source's host batches
+    as they come: each window's record equals a host union-find over the
+    edges pushed so far, no stage-less stream ran a pipeline step, and the
+    scheduler's in-flight bound probes the record's device arrays."""
+    from fixtures import host_min_labels
+
+    from gelly_streaming_tpu.core.stream import EdgeStream
+
+    from gelly_streaming_tpu.runtime import manager as mgr
+
+    stepped, probed = [], []
+    orig_step, orig_computed = EdgeStream._compiled_step, mgr._computed
+
+    def spy_step(self):
+        stepped.append(self._stages)
+        return orig_step(self)
+
+    def spy_computed(rec):
+        probed.append(len(mgr._device_leaves(rec)))
+        return orig_computed(rec)
+
+    monkeypatch.setattr(EdgeStream, "_compiled_step", spy_step)
+    monkeypatch.setattr(mgr, "_computed", spy_computed)
+    s, d = _graph(41)
+    with JobManager() as jm, StreamServer(jm, ServerConfig()) as server:
+        with GellyClient("127.0.0.1", server.port) as c:
+            c.submit(name="cc-direct", query="cc", capacity=CAP,
+                     window_edges=W, batch=B)
+            c.push_edges("cc-direct", s, d, batch=B, capacity=CAP)
+            records = list(c.iter_results("cc-direct", deadline_s=240))
+    assert len(records) == N // W
+    for k, (_cap, parent, seen) in enumerate(records):
+        hi = (k + 1) * W
+        roots = np.asarray(parent)
+        while not np.array_equal(roots[roots], roots):
+            roots = roots[roots]
+        # union by min root: each root is its component's smallest id
+        np.testing.assert_array_equal(
+            roots, host_min_labels(CAP, s[:hi], d[:hi]), f"window {k}"
+        )
+        want_seen = np.zeros(CAP, bool)
+        want_seen[s[:hi]] = want_seen[d[:hi]] = True
+        np.testing.assert_array_equal(np.asarray(seen, bool), want_seen)
+    assert () not in stepped, "a stage-less stream ran the pipeline step"
+    # the scheduler's in-flight bound sees each record's parent and seen
+    assert probed and set(probed) == {2}, probed
+
+
 # ---------------------------------------------------------------------------
 # protocol robustness: refusal, never a hang or a dirty close
 # ---------------------------------------------------------------------------
