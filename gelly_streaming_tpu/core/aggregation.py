@@ -20,6 +20,7 @@ from __future__ import annotations
 import functools
 import os
 import time
+from types import GeneratorType
 from typing import Any, Callable, Iterator, Optional
 
 import jax
@@ -32,6 +33,7 @@ from gelly_streaming_tpu.core.output import OutputStream
 from gelly_streaming_tpu.core.windows import (
     FoldRequest,
     WindowPane,
+    pad_pane_edges,
     stream_panes,
 )
 from gelly_streaming_tpu.utils import metrics, tracing
@@ -61,6 +63,104 @@ def resolve_fused_dispatch(cfg: StreamConfig) -> bool:
     from gelly_streaming_tpu.utils.envswitch import resolve_switch
 
     return resolve_switch(cfg.fused_dispatch, "GELLY_FUSED_DISPATCH", False)
+
+
+class MergerState:
+    """The Merger's state (SummaryAggregation.java:93-135): the running
+    summary and the stream position — the last folded window id, and
+    whether the untimed global pane (id -1, which has no orderable id) was
+    folded.
+
+    The one owner of the windowed snapshot layout
+    (``SummaryAggregation._checkpoint_like``) and of the pane route: the
+    constructor is the layout's only reader, :meth:`save` its only writer,
+    and :meth:`merge` the only place a window's fold is routed (and
+    counted) into the running summary.  Both Merger loops
+    (``SummaryAggregation._merge_loop`` and its async form,
+    ``async_exec.async_merge_loop``) and every reader of a windowed
+    snapshot's position go through it.
+    """
+
+    __slots__ = ("agg", "path", "running", "start_after", "global_done")
+
+    def __init__(self, agg, cfg, checkpoint_path=None, restore=True):
+        self.agg = agg
+        self.path = checkpoint_path
+        self.running = None
+        self.start_after = -1
+        self.global_done = False
+        if not (checkpoint_path and restore):
+            return
+        from gelly_streaming_tpu.utils.checkpoint import (
+            checkpoint_exists,
+            load_state,
+        )
+
+        if not checkpoint_exists(checkpoint_path):
+            return
+        try:
+            snap = load_state(checkpoint_path, agg._checkpoint_like(cfg))
+        except ValueError:
+            # legacy snapshot layout: a bare summary pytree with no stream
+            # position (pre-position checkpoints)
+            self.running = load_state(checkpoint_path, agg.initial_state(cfg))
+            return
+        if bool(snap["has_summary"]):
+            self.running = snap["summary"]
+        self.start_after = int(snap["last_window"])
+        self.global_done = bool(snap["global_done"])
+
+    def folded(self, window_id: int) -> bool:
+        """Folded before the snapshot: a replayed pane to skip."""
+        return 0 <= window_id <= self.start_after or (
+            window_id == -1 and self.global_done
+        )
+
+    def merge(self, pane_summary, into_running: bool = False):
+        """Route one window's fold into the running summary and return it:
+        ``into_running`` folds already hold the running summary; a
+        transient descriptor's pane alone is its window's summary; any
+        other pane's partial combines into the running summary."""
+        if into_running:
+            self.running = pane_summary
+            metrics.pipeline_add("merge_running_folds", 1)
+        elif self.agg.transient_state:
+            self.running = pane_summary
+            metrics.pipeline_add("merge_transient_folds", 1)
+        else:
+            if self.running is not None:
+                pane_summary = self.agg._combine_j(self.running, pane_summary)
+            self.running = pane_summary
+            metrics.pipeline_add("merge_combines", 1)
+        return self.running
+
+    def advance(self, window_id: int) -> None:
+        """The window is emitted: the position moves past it."""
+        self.start_after = max(window_id, self.start_after)
+        self.global_done = self.global_done or window_id == -1
+
+    def save(self, summary) -> None:
+        """Snapshot ``summary`` at this position (no-op without a path).
+        Transient aggregations reset after emission, so their snapshot
+        restores with no running summary."""
+        if not self.path:
+            return
+        from gelly_streaming_tpu.utils.checkpoint import save_state
+
+        save_state(
+            self.path,
+            {
+                "summary": summary,
+                "has_summary": np.full((), not self.agg.transient_state, bool),
+                "last_window": np.full((), self.start_after, np.int64),
+                "global_done": np.full((), self.global_done, bool),
+            },
+        )
+
+    def reset(self) -> None:
+        """After emission: a transient descriptor starts each window anew."""
+        if self.agg.transient_state:
+            self.running = None
 
 
 class SummaryAggregation:
@@ -559,11 +659,11 @@ class SummaryAggregation:
             snap = load_state(checkpoint_path, self._wire_checkpoint_like(stream))
         except ValueError:  # a pre-wire-path (windowed-layout) snapshot
             try:
-                legacy = load_state(checkpoint_path, self._checkpoint_like(cfg))
+                legacy = MergerState(self, cfg, checkpoint_path)
             except ValueError:
-                return 0, None, None  # bare-summary snapshot: no position
-            if bool(legacy["global_done"]) and bool(legacy["has_summary"]):
-                return 0, None, legacy["summary"]
+                return 0, None, None  # no readable layout: no position
+            if legacy.global_done and legacy.running is not None:
+                return 0, None, legacy.running
             return 0, None, None
         if int(snap["batch"]) != batch:
             raise ValueError(
@@ -1004,7 +1104,7 @@ class SummaryAggregation:
             # merges/emits/checkpoints per window, so the record sequence
             # and recovery semantics are identical to per-pane dispatch.
             def records_sb() -> Iterator[tuple]:
-                skip_through, skip_global = self._restored_position(
+                skip_through, skip_global = self.restored_position(
                     cfg, checkpoint_path, restore
                 )
                 return self._merge_loop(
@@ -1092,7 +1192,7 @@ class SummaryAggregation:
                 fold_pane,
                 checkpoint_path,
                 restore,
-                fold_into_running=into_running,
+                into_running=into_running,
             )
 
         return OutputStream(records)
@@ -1138,196 +1238,92 @@ class SummaryAggregation:
         plain ``next()`` — Python defines that as ``send(None)`` — so a
         dropped/parked quantum, a paused-then-resumed job, or a naive
         iterator consumer all degrade to correct solo dispatch rather
-        than losing the window.  Everything downstream of the fold
-        (running merge order, transform, at-least-once emission,
-        positional checkpoints, transient resets) is ``_merge_loop``'s
-        logic verbatim, so fused and solo record sequences are
-        bit-identical (pinned by tests/test_fused_dispatch.py).
+        than losing the window.  The plane is ``_merge_loop`` with
+        ``_fused_fold`` as its fold step, so everything downstream of the
+        fold (running merge order, transform, at-least-once emission,
+        positional checkpoints, transient resets) is the sync plane's, and
+        fused and solo record sequences are bit-identical (pinned by
+        tests/test_fused_dispatch.py).  Fused-eligible streams only
+        (``fused_eligible``: the synchronous single-partition plane).
         """
-        if checkpoint_path and stream.cfg.ingest_window_ms:
+        cfg = stream.cfg
+        if checkpoint_path and cfg.ingest_window_ms:
             raise ValueError(
                 "wall-clock ingestion panes (ingest_window_ms) are not "
                 "replay-deterministic: a resume would skip panes by id that "
                 "cover different edges than the crashed run's; use "
                 "ingest_window_edges for checkpointed runs"
             )
-        return self._fused_pane_records(stream, checkpoint_path, restore)
+        return self._merge_loop(
+            cfg,
+            stream_panes(stream, self.window_ms or cfg.window_ms, mark_close=True),
+            functools.partial(self._fused_fold, cfg),
+            checkpoint_path,
+            restore,
+        )
 
-    def _fused_pane_records(
-        self,
-        stream,
-        checkpoint_path: Optional[str],
-        restore: bool,
-    ) -> Iterator[tuple]:
-        """Merger loop with the per-pane fold handed to the cohort consumer
-        (see ``run_fused``).  Mirrors ``_merge_loop`` + the sync
-        ``fold_pane``'s combine route (each pane folds from the initial
-        state: a cohort row cannot read its job's running summary); for an
-        order-free fold that route's records equal the sync plane's
-        fold-into-running ones.  Any drift here is a correctness bug, not
-        a style one."""
-        cfg = stream.cfg
-        window_ms = self.window_ms or cfg.window_ms
-        running = None
-        start_after = -1
-        global_done = False
-        if checkpoint_path and restore:
-            from gelly_streaming_tpu.utils.checkpoint import (
-                checkpoint_exists,
-                load_state,
-            )
+    def _fused_fold(self, cfg: StreamConfig, pane: WindowPane):
+        """The fused cohort plane's fold step (see ``run_fused``): a
+        generator that parks the pane's padded fold at a ``FoldRequest``
+        and returns its partial from the initial state — a cohort row
+        cannot read its job's running summary, so the Merger combines it
+        (for an order-free fold those records equal the sync plane's
+        fold-into-running ones)."""
+        pane = self._maybe_bin_pane(cfg, pane)
+        n = pane.num_edges
+        if n == 0:
+            return None  # empty pane: the sync fold returns None too
+        # the sync plane's pow2 pad, materialized as the offered row
+        src, dst, msk = pad_pane_edges(pane)
+        e_pad = len(src)
+        val = None
+        if pane.val is not None:
 
-            if checkpoint_exists(checkpoint_path):
-                try:
-                    snap = load_state(checkpoint_path, self._checkpoint_like(cfg))
-                    if bool(snap["has_summary"]):
-                        running = snap["summary"]
-                    start_after = int(snap["last_window"])
-                    global_done = bool(snap["global_done"])
-                except ValueError:
-                    # legacy snapshot layout: a bare summary pytree with
-                    # no stream position (pre-position checkpoints)
-                    running = load_state(checkpoint_path, self.initial_state(cfg))
-        span_sampler = tracing.sampler(cfg, "merge")
-        token = self.cache_token
-        split = functools.partial(self._superpane_split_fn, cfg)
-        for pane in stream_panes(stream, window_ms, mark_close=True):
-            already_folded = (0 <= pane.window_id <= start_after) or (
-                pane.window_id == -1 and global_done
-            )
-            if already_folded:
-                tracing.drop_cut()
-                continue  # folded before the snapshot: replay-safe
-            span = (
-                span_sampler.begin(pane.window_id)
-                if span_sampler is not None
-                else None
-            )
-            tracing.enter_window(pane.window_id, span)
-            pane = self._maybe_bin_pane(cfg, pane)
-            n = pane.num_edges
-            if n == 0:
-                tracing.leave_window()
-                continue  # empty pane: the sync fold returns None too
-            # the sync plane's pow2 pad, materialized as the offered row
-            e_pad = max(1, 1 << (n - 1).bit_length())
-            src = np.zeros((e_pad,), np.int32)
-            dst = np.zeros((e_pad,), np.int32)
-            msk = np.zeros((e_pad,), bool)
-            src[:n], dst[:n], msk[:n] = pane.src, pane.dst, True
-            val = None
-            if pane.val is not None:
+            def pad(a):
+                out = np.zeros((e_pad,) + a.shape[1:], a.dtype)
+                out[:n] = a
+                return out
 
-                def pad(a):
-                    out = np.zeros((e_pad,) + a.shape[1:], a.dtype)
-                    out[:n] = a
-                    return out
-
-                val = jax.tree.map(pad, pane.val)
-            has_val = val is not None
-            tracing.end_cut()
-            partial = yield FoldRequest(
-                key=(token, cfg, has_val, e_pad),
-                fold=self._superpane_fold_fn(cfg, has_val),
-                split=split,
-                src=src,
-                dst=dst,
-                val=val,
-                mask=msk,
-                window_id=pane.window_id,
-                edges=n,
-            )
-            # the cohort round ran other jobs' windows on this thread
-            tracing.scope_window(pane.window_id, span)
-            if partial is None:
-                # solo fallback: no same-key peers this round (or a
-                # protocol-naive resume) — fold the identical padded
-                # arrays through the plain plane's executable
-                with tracing.span("gelly.pane.put"):
-                    put = (
-                        jnp.asarray(src),
-                        jnp.asarray(dst),
-                        None if val is None else jax.tree.map(jnp.asarray, val),
-                        jnp.asarray(msk),
-                    )
-                with tracing.span("gelly.fold.dispatch"):
-                    partial = self._update_j(self.initial_state(cfg), *put)
-            with tracing.span("gelly.combine.dispatch"):
-                if self.transient_state:
-                    running = partial
-                    metrics.pipeline_add("merge_transient_folds", 1)
-                else:
-                    if running is None:
-                        running = partial
-                    else:
-                        running = self._combine_j(running, partial)
-                    metrics.pipeline_add("merge_combines", 1)
-                out = self.transform(running)
-            # Emit BEFORE snapshotting: a crash between the two re-emits
-            # this window on recovery (at-least-once emission) instead of
-            # dropping it (at-most-once would lose sink data).
-            emission = tracing.emit()
-            yield out if isinstance(out, tuple) else (out,)
-            tracing.settle(emission)
-            start_after = max(pane.window_id, start_after)
-            global_done = global_done or pane.window_id == -1
-            if checkpoint_path:
-                from gelly_streaming_tpu.utils.checkpoint import save_state
-
-                # transient aggregations reset after emission, so a
-                # restore must come back with no running summary
-                save_state(
-                    checkpoint_path,
-                    {
-                        "summary": running,
-                        "has_summary": np.full((), not self.transient_state, bool),
-                        "last_window": np.full((), start_after, np.int64),
-                        "global_done": np.full((), global_done, bool),
-                    },
+            val = jax.tree.map(pad, pane.val)
+        has_val = val is not None
+        tracing.end_cut()
+        partial = yield FoldRequest(
+            key=(self.cache_token, cfg, has_val, e_pad),
+            fold=self._superpane_fold_fn(cfg, has_val),
+            split=functools.partial(self._superpane_split_fn, cfg),
+            src=src,
+            dst=dst,
+            val=val,
+            mask=msk,
+            window_id=pane.window_id,
+            edges=n,
+        )
+        if partial is None:
+            # solo fallback: no same-key peers this round (or a
+            # protocol-naive resume) — fold the identical padded arrays
+            # through the plain plane's executable
+            with tracing.span("gelly.pane.put"):
+                put = (
+                    jnp.asarray(src),
+                    jnp.asarray(dst),
+                    None if val is None else jax.tree.map(jnp.asarray, val),
+                    jnp.asarray(msk),
                 )
-            if self.transient_state:
-                running = None
+            with tracing.span("gelly.fold.dispatch"):
+                partial = self._update_j(self.initial_state(cfg), *put)
+        return partial
 
-    def _restored_position(self, cfg, checkpoint_path, restore):
-        """(last folded window id, global pane done) from a windowed-layout
-        snapshot — for gating pane prefetch/fold work ahead of the merge
-        loop, which re-reads the position itself and remains the source of
-        truth.  (-1, False) when there is nothing to restore."""
-        if not (checkpoint_path and restore):
-            return -1, False
-        from gelly_streaming_tpu.utils.checkpoint import (
-            checkpoint_exists,
-            load_state,
-        )
-
-        if not checkpoint_exists(checkpoint_path):
-            return -1, False
+    def restored_position(self, cfg, checkpoint_path, restore=True):
+        """(last folded window id, global pane done) of the windowed
+        snapshot at ``checkpoint_path`` — for gating pane work ahead of the
+        Merger loop, which restores its own state and stays the source of
+        truth, and for the served path's resume cursors.  (-1, False) when
+        there is nothing to restore, or the snapshot is another plane's."""
         try:
-            snap = load_state(checkpoint_path, self._checkpoint_like(cfg))
+            state = MergerState(self, cfg, checkpoint_path, restore)
         except ValueError:
-            return -1, False  # legacy layout: merge loop sorts it out
-        return int(snap["last_window"]), bool(snap["global_done"])
-
-    def _restored_summary(self, cfg, checkpoint_path, restore):
-        """The snapshot's running summary pytree, or None — for seeding
-        persistent sharded blocks on resume (the merge loop re-reads the
-        position itself and stays the source of truth)."""
-        if not (checkpoint_path and restore):
-            return None
-        from gelly_streaming_tpu.utils.checkpoint import (
-            checkpoint_exists,
-            load_state,
-        )
-
-        if not checkpoint_exists(checkpoint_path):
-            return None
-        try:
-            snap = load_state(checkpoint_path, self._checkpoint_like(cfg))
-        except ValueError:
-            return None
-        if not bool(snap["has_summary"]):
-            return None
-        return snap["summary"]
+            return -1, False
+        return state.start_after, state.global_done
 
     def _async_pane_records(
         self,
@@ -1354,7 +1350,7 @@ class SummaryAggregation:
 
         cfg = stream.cfg
         depth = async_exec.resolve_depth(cfg)
-        skip_through, skip_global = self._restored_position(
+        skip_through, skip_global = self.restored_position(
             cfg, checkpoint_path, restore
         )
         # retention cap sized to the pipeline's own in-flight bound (two
@@ -1600,29 +1596,31 @@ class SummaryAggregation:
         restore: bool,
         unwrap: bool = False,
         release: Optional[Callable] = None,
-        fold_is_running: bool = False,
-        fold_into_running: bool = False,
+        into_running: bool = False,
     ) -> Iterator[tuple]:
         """The Merger: running merge + emission + positional checkpointing
-        (SummaryAggregation.java:93-135), shared by the simulated and mesh
-        execution paths so their recovery semantics cannot diverge.
+        (SummaryAggregation.java:93-135), shared by every windowed plane
+        (simulated, fused, superbatch, async, mesh) so their recovery
+        semantics cannot diverge; ``MergerState`` holds its state, snapshot
+        layout and pane route.
 
-        ``fold_is_running`` (the owner-sharded plane): ``fold_pane`` folds
-        into PERSISTENT cross-window state and returns the running summary
-        itself, so the loop skips the combine step — emission order,
-        transient resets, and checkpoint semantics are unchanged.
-        ``fold_into_running`` (order-free single-partition folds, sync
-        only): the loop hands ``fold_pane(payload, running)`` the running
-        summary (None before the first window; the restored one after a
-        restore) and takes the result as the new running summary, again
-        with no combine.
-
-        ``fold_pane(pane) -> summary | None`` supplies the per-pane partial
-        fold+combine; everything downstream (merge order, transient reset,
-        at-least-once emission, snapshot layout) is common.  With ``unwrap``
-        the iterator yields (pane, payload) pairs — position/window logic
-        reads the pane, the payload goes to ``fold_pane`` (the mesh runner
-        attaches prefetched device buffers this way).
+        ``fold_pane(payload) -> summary | None`` supplies the per-pane
+        partial fold+combine; everything downstream (merge order,
+        transient reset, at-least-once emission, snapshot layout) is
+        common.  With ``into_running`` the loop hands it the running
+        summary, ``fold_pane(payload, running)`` (None before the first
+        window; the restored one after a restore), and takes the result as
+        the new running summary with no combine: order-free
+        single-partition folds fold straight into it, and the
+        owner-sharded plane folds into persistent cross-window blocks and
+        returns the running summary itself.  A fold step may also be a
+        generator that parks at a ``FoldRequest`` (the fused cohort
+        plane, ``run_fused``): the loop yields the request to its consumer
+        and takes the generator's return value as the pane's summary.
+        With ``unwrap`` the iterator yields (pane, payload) pairs —
+        position/window logic reads the pane, the payload goes to
+        ``fold_pane`` (the mesh runner attaches prefetched device buffers
+        this way).
 
         With ``cfg.async_windows`` > 0 the loop runs in its asynchronous
         form (core/async_exec.async_merge_loop): folds dispatch without
@@ -1646,38 +1644,16 @@ class SummaryAggregation:
                 unwrap=unwrap,
                 depth=depth,
                 release=release,
-                fold_is_running=fold_is_running,
+                into_running=into_running,
             )
             return
-        running = None
-        start_after = -1
-        global_done = False
-        if checkpoint_path and restore:
-            from gelly_streaming_tpu.utils.checkpoint import (
-                checkpoint_exists,
-                load_state,
-            )
-
-            if checkpoint_exists(checkpoint_path):
-                try:
-                    snap = load_state(checkpoint_path, self._checkpoint_like(cfg))
-                    if bool(snap["has_summary"]):
-                        running = snap["summary"]
-                    start_after = int(snap["last_window"])
-                    global_done = bool(snap["global_done"])
-                except ValueError:
-                    # legacy snapshot layout: a bare summary pytree with
-                    # no stream position (pre-position checkpoints)
-                    running = load_state(checkpoint_path, self.initial_state(cfg))
+        state = MergerState(self, cfg, checkpoint_path, restore)
         # span sampling resolved ONCE: when off (the default) the loop
         # below pays a single `is not None` branch per window
         span_sampler = tracing.sampler(cfg, "merge")
         for item in panes:
             pane, payload = item if unwrap else (item, item)
-            already_folded = (0 <= pane.window_id <= start_after) or (
-                pane.window_id == -1 and global_done
-            )
-            if already_folded:
+            if state.folded(pane.window_id):
                 tracing.drop_cut()
                 continue  # folded before the snapshot: replay-safe
             span = (
@@ -1688,10 +1664,15 @@ class SummaryAggregation:
             # the window's spans (cut, put, fold, combine here; sink wait
             # and copy on the consumer's side) share its (job, window)
             tracing.enter_window(pane.window_id, span)
-            if fold_into_running:
-                pane_summary = fold_pane(payload, running)
+            if into_running:
+                pane_summary = fold_pane(payload, state.running)
             else:
                 pane_summary = fold_pane(payload)
+            if isinstance(pane_summary, GeneratorType):
+                # a cohort member's fold parks at its FoldRequest; the
+                # round runs other jobs' windows on this thread
+                pane_summary = yield from pane_summary
+                tracing.scope_window(pane.window_id, span)
             tracing.end_cut()
             if pane_summary is None:
                 tracing.leave_window()
@@ -1699,20 +1680,7 @@ class SummaryAggregation:
             # Merger: non-blocking running merge, one emission per window
             # close (SummaryAggregation.java:107-119).
             with tracing.span("gelly.combine.dispatch"):
-                if fold_is_running or fold_into_running:
-                    running = pane_summary
-                    metrics.pipeline_add("merge_running_folds", 1)
-                elif self.transient_state:
-                    # the pane alone is the window's summary: no combine
-                    running = pane_summary
-                    metrics.pipeline_add("merge_transient_folds", 1)
-                else:
-                    if running is None:
-                        running = pane_summary
-                    else:
-                        running = self._combine_j(running, pane_summary)
-                    metrics.pipeline_add("merge_combines", 1)
-                out = self.transform(running)
+                out = self.transform(state.merge(pane_summary, into_running))
             # Emit BEFORE snapshotting: a crash between the two re-emits
             # this window on recovery (at-least-once emission) instead of
             # dropping it (at-most-once would lose sink data).  The
@@ -1721,24 +1689,9 @@ class SummaryAggregation:
             emission = tracing.emit()
             yield out if isinstance(out, tuple) else (out,)
             tracing.settle(emission)
-            start_after = max(pane.window_id, start_after)
-            global_done = global_done or pane.window_id == -1
-            if checkpoint_path:
-                from gelly_streaming_tpu.utils.checkpoint import save_state
-
-                # transient aggregations reset after emission, so a
-                # restore must come back with no running summary
-                save_state(
-                    checkpoint_path,
-                    {
-                        "summary": running,
-                        "has_summary": np.full((), not self.transient_state, bool),
-                        "last_window": np.full((), start_after, np.int64),
-                        "global_done": np.full((), global_done, bool),
-                    },
-                )
-            if self.transient_state:
-                running = None
+            state.advance(pane.window_id)
+            state.save(state.running)
+            state.reset()
 
 
 class SummaryBulkAggregation(SummaryAggregation):
@@ -2731,12 +2684,6 @@ class MeshAggregationRunner:
         )
         return finish(carry)
 
-    def _restored_position(self, cfg, checkpoint_path, restore):
-        """(last folded window id, global pane done) — shared reader on the
-        descriptor (SummaryAggregation._restored_position); the merge loop
-        remains the source of truth for folding position."""
-        return self.agg._restored_position(cfg, checkpoint_path, restore)
-
     def _pane_cap(self, total: int) -> int:
         from gelly_streaming_tpu.parallel.routing import pow2_bucket
 
@@ -2902,7 +2849,7 @@ class MeshAggregationRunner:
         when the spec asks for it — ``spec.route_key`` — else the skew-free
         round-robin), folded + delta-exchanged + lazily gathered in one
         dispatch, and the gathered running summary rides the shared Merger
-        loop (``fold_is_running``) so emission order, at-least-once
+        loop (``into_running``) so emission order, at-least-once
         semantics, and positional checkpoints are identical to the
         replicated plane — which stays available as the equivalence oracle
         (cfg.sharded_state=0).
@@ -2915,7 +2862,7 @@ class MeshAggregationRunner:
         agg = self.agg
         S = self.num_shards
         width = agg._wire_width(cfg)
-        skip_through, skip_global = self._restored_position(
+        skip_through, skip_global = agg.restored_position(
             cfg, checkpoint_path, restore
         )
 
@@ -2991,7 +2938,7 @@ class MeshAggregationRunner:
             import collections as _collections
 
             sharding = self._sharded_blocks_sharding()
-            restored = agg._restored_summary(cfg, checkpoint_path, restore)
+            restored = MergerState(agg, cfg, checkpoint_path, restore).running
             if restored is not None:
                 blocks = jax.device_put(
                     spec.shard_summary(restored, cfg, S), sharding
@@ -3007,7 +2954,7 @@ class MeshAggregationRunner:
                     stats, profile = pending_stats.popleft()
                     self._record_exchange_stats(profile, stats)
 
-            def fold_prepared(item):
+            def fold_prepared(item, _running):
                 nonlocal blocks
                 (pane, kind, cap), dev = item
                 if kind is None:
@@ -3044,7 +2991,7 @@ class MeshAggregationRunner:
                         checkpoint_path,
                         restore,
                         unwrap=True,
-                        fold_is_running=True,
+                        into_running=True,
                     )
             finally:
                 drain_stats(0)
@@ -3089,7 +3036,7 @@ class MeshAggregationRunner:
         # value-less panes honor the configured wire encoding exactly as the
         # single-shard fast path does (incl. the order-free EF40 gate)
         width = agg._wire_width(cfg)
-        skip_through, skip_global = self._restored_position(
+        skip_through, skip_global = agg.restored_position(
             cfg, checkpoint_path, restore
         )
 

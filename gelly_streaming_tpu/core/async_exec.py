@@ -42,6 +42,7 @@ from typing import Callable, Iterable, Iterator, Optional
 
 import numpy as np
 
+from gelly_streaming_tpu.core import output
 from gelly_streaming_tpu.utils import metrics, tracing
 
 
@@ -65,20 +66,17 @@ def resolve_depth(cfg) -> int:
 
 
 def start_host_fetch(tree) -> None:
-    """Kick off the device->host copy of every array leaf (non-blocking).
+    """Kick off the device->host copy of every device leaf (non-blocking).
 
     The completion-queue contract: emissions enter the queue with their
     downloads already in flight, so the drain's materialization waits on a
     copy that has been overlapping later windows' compute, not a fresh RTT.
-    Host-side leaves (numpy, python scalars) need no copy and are skipped.
+    The leaves are the record walk's (``output.device_leaves``), so a host
+    wrapper's arrays (connected components' ``DisjointSet``) are copied
+    too; host-side leaves need no copy.
     """
-    import jax
-
-    for leaf in jax.tree.leaves(tree):
-        try:
-            leaf.copy_to_host_async()
-        except AttributeError:
-            pass
+    for leaf in output.device_leaves(tree):
+        leaf.copy_to_host_async()
 
 
 def wait_ready(tree) -> None:
@@ -89,14 +87,9 @@ def wait_ready(tree) -> None:
     lint allowlist).  Used before recycling an arena whose host memory the
     fold may still be reading through a zero-copy transfer.
     """
-    import jax
-
     t0 = time.perf_counter()
-    for leaf in jax.tree.leaves(tree):
-        try:
-            leaf.block_until_ready()  # hot-loop-ok: completion-queue drain
-        except AttributeError:
-            pass
+    for leaf in output.device_leaves(tree):
+        leaf.block_until_ready()  # hot-loop-ok: completion-queue drain
     metrics.pipeline_add(
         "pipeline_drain_stall_s", time.perf_counter() - t0
     )
@@ -256,14 +249,15 @@ def async_merge_loop(
     unwrap: bool = False,
     depth: int = 2,
     release: Optional[Callable] = None,
-    fold_is_running: bool = False,
+    into_running: bool = False,
 ) -> Iterator[tuple]:
     """The Merger with a non-blocking completion queue
-    (SummaryAggregation._merge_loop's async form — same restore, merge,
-    emission-order, and at-least-once semantics, pinned by
+    (SummaryAggregation._merge_loop's async form — the same
+    ``MergerState`` restore, route and snapshot, and the same
+    emission-order and at-least-once semantics, pinned by
     tests/test_async_windows.py).
 
-    ``fold_is_running`` mirrors the synchronous loop: the owner-sharded
+    ``into_running`` mirrors the synchronous loop: the owner-sharded
     plane's folds accumulate into persistent cross-window blocks and return
     the running summary directly, so no combine is dispatched here — the
     double-buffered route -> fold -> exchange schedule stays non-blocking
@@ -282,65 +276,36 @@ def async_merge_loop(
     drain time; it is called only after the window's FOLD OUTPUT is known
     complete (``wait_ready``), which proves the arena's host memory is no
     longer read (donation-safe ownership, see ArenaPool).  The fold output
-    — not the emission record — is the wait target because transforms may
-    wrap state in non-pytree host objects whose leaves wait_ready cannot
-    block on.
+    is the wait target because it is what consumed the arena: the record
+    is only derived from it.
     """
-    running = None
-    start_after = -1
-    global_done = False
-    if checkpoint_path and restore:
-        from gelly_streaming_tpu.utils.checkpoint import (
-            checkpoint_exists,
-            load_state,
-        )
+    from gelly_streaming_tpu.core.aggregation import MergerState
 
-        if checkpoint_exists(checkpoint_path):
-            try:
-                snap = load_state(checkpoint_path, agg._checkpoint_like(cfg))
-                if bool(snap["has_summary"]):
-                    running = snap["summary"]
-                start_after = int(snap["last_window"])
-                global_done = bool(snap["global_done"])
-            except ValueError:
-                # legacy snapshot layout: a bare summary pytree with no
-                # stream position (pre-position checkpoints)
-                running = load_state(checkpoint_path, agg.initial_state(cfg))
+    # the dispatch frontier (skips, route) and, up to ``depth`` windows
+    # behind it, the drained frontier the snapshots cover
+    state = MergerState(agg, cfg, checkpoint_path, restore)
+    drained = MergerState(agg, cfg, checkpoint_path, restore=False)
+    drained.start_after, drained.global_done = state.start_after, state.global_done
 
-    # completion queue: (window_id, record, ckpt summary, global_done after
-    # this window, release payload) in dispatch (= window) order
+    # completion queue: (window_id, record, ckpt summary, release payload,
+    # span, close time, fold output) in dispatch (= window) order
     pending: "collections.deque" = collections.deque()
 
-    def save(wid_through: int, gdone: bool, summary) -> None:
-        from gelly_streaming_tpu.utils.checkpoint import save_state
-
-        t0 = time.perf_counter()
-        save_state(
-            checkpoint_path,
-            {
-                "summary": summary,
-                "has_summary": np.full((), not agg.transient_state, bool),
-                "last_window": np.full((), wid_through, np.int64),
-                "global_done": np.full((), gdone, bool),
-            },
-        )
-        metrics.pipeline_add(
-            "pipeline_drain_stall_s", time.perf_counter() - t0
-        )
-
-    drained_through = start_after
-    drained_global = global_done
+    def settle(wid: int, summary) -> None:
+        """The drained window's record was consumed: snapshot past it."""
+        drained.advance(wid)
+        if checkpoint_path:
+            t0 = time.perf_counter()
+            drained.save(summary)
+            metrics.pipeline_add(
+                "pipeline_drain_stall_s", time.perf_counter() - t0
+            )
 
     def drain_one():
-        nonlocal drained_through, drained_global
         wid, rec, summary, payload, span, t_item, fold_out = pending.popleft()
         metrics.pipeline_add("pipeline_windows_drained", 1)
         t_drain = time.perf_counter()
         if release is not None and payload is not None:
-            # wait on the FOLD OUTPUT, not the transformed record: transforms
-            # may return non-pytree host wrappers (e.g. CC's DisjointSet)
-            # whose opaque leaves wait_ready silently skips, which would
-            # recycle the arena under a still-pending zero-copy fold
             wait_ready(fold_out)
             release(payload)  # arena-live-until: drain — this IS the drain
         t_emit = time.perf_counter()
@@ -369,35 +334,23 @@ def async_merge_loop(
                 "pipeline_dispatch_stall_s", time.perf_counter() - t_pull
             )
             pane, payload = item if unwrap else (item, item)
-            already_folded = (0 <= pane.window_id <= start_after) or (
-                pane.window_id == -1 and global_done
-            )
-            if already_folded:
+            if state.folded(pane.window_id):
                 continue  # folded before the snapshot: replay-safe
             # the span (if this window was sampled at the pack thread)
             # rides the payload meta; its dispatch stage covers the fold
             # dispatch + transform + host-fetch kickoff below
             span = tracing.find_span(payload) if tracing.active() else None
             t_item = time.perf_counter()
-            pane_summary = fold_pane(payload)
+            if into_running:
+                pane_summary = fold_pane(payload, state.running)
+            else:
+                pane_summary = fold_pane(payload)
             if pane_summary is None:
                 continue
-            if fold_is_running:
-                running = pane_summary
-                metrics.pipeline_add("merge_running_folds", 1)
-            elif agg.transient_state:
-                running = pane_summary
-                metrics.pipeline_add("merge_transient_folds", 1)
-            else:
-                if running is None:
-                    running = pane_summary
-                else:
-                    running = agg._combine_j(running, pane_summary)
-                metrics.pipeline_add("merge_combines", 1)
-            out = agg.transform(running)
+            out = agg.transform(state.merge(pane_summary, into_running))
             rec = out if isinstance(out, tuple) else (out,)
             start_host_fetch(rec)
-            ck = running if checkpoint_path else None
+            ck = state.running if checkpoint_path else None
             if ck is not None:
                 start_host_fetch(ck)
             if span is not None:
@@ -417,24 +370,19 @@ def async_merge_loop(
             metrics.pipeline_high_water(
                 "pipeline_inflight_high_water", len(pending)
             )
-            start_after = max(pane.window_id, start_after)
-            global_done = global_done or pane.window_id == -1
-            if agg.transient_state:
-                running = None
+            state.advance(pane.window_id)
+            state.reset()
             while len(pending) > depth:
                 wid, rec_d, summary = drain_one()
                 yield rec_d
-                drained_through = max(wid, drained_through)
-                drained_global = drained_global or wid == -1
-                if checkpoint_path:
-                    save(drained_through, drained_global, summary)
+                settle(wid, summary)
         # hot-loop-end
     except GeneratorExit:
         # consumer closed (JobManager.cancel / Job.close / an abandoned
         # run): yielding is illegal here, but the completion queue still
         # holds in-flight windows whose arenas are owned by dispatched
         # folds.  Run them through the NORMAL drain path — drain_one waits
-        # on each window's emission (proving its fold consumed the arena's
+        # on each window's fold output (proving it consumed the arena's
         # host memory) and releases the arenas — discarding the records, so
         # a mid-flight cancel recycles every arena without corrupting one a
         # zero-copy transfer still reads.
@@ -447,15 +395,9 @@ def async_merge_loop(
         while pending:
             wid, rec_d, summary = drain_one()
             yield rec_d
-            drained_through = max(wid, drained_through)
-            drained_global = drained_global or wid == -1
-            if checkpoint_path:
-                save(drained_through, drained_global, summary)
+            settle(wid, summary)
         raise
     while pending:
         wid, rec_d, summary = drain_one()
         yield rec_d
-        drained_through = max(wid, drained_through)
-        drained_global = drained_global or wid == -1
-        if checkpoint_path:
-            save(drained_through, drained_global, summary)
+        settle(wid, summary)

@@ -7,6 +7,11 @@ blocks (dict of equal-length host arrays + validity mask); ``OutputStream``
 wraps that iterator with collect/CSV sinks using the same rendering the golden
 files assert (Flink Tuple CSV: ``1,2,12``; NullValue -> ``(null)``; nested
 tuples -> ``(12,13)``).
+
+A windowed plane's emission record is walked to its leaves in one place
+(``device_leaves`` / ``record_leaves``): the served wire format, the
+scheduler's in-flight probe and the async plane's host fetch all see the
+same leaves in the same order.
 """
 
 from __future__ import annotations
@@ -14,6 +19,8 @@ from __future__ import annotations
 from typing import Callable, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
+
+from gelly_streaming_tpu.utils import tracing
 
 
 class NullValue:
@@ -183,3 +190,93 @@ class OutputStream:
     def print(self) -> None:
         for rec in self:
             print(",".join(_render(f) for f in rec))
+
+
+def device_leaves(rec) -> list:
+    """The device arrays of one emission record, in wire order (see
+    :func:`record_leaves`): what its host copy waits on and copies, what
+    the scheduler's in-flight probe checks, what the async plane starts
+    copying.  Never blocks, never converts a leaf."""
+    import jax
+
+    out: list = []
+    _collect_leaves(rec, out, jax.Array, [])
+    return [x for x in out if isinstance(x, jax.Array)]
+
+
+def record_leaves(rec) -> list:
+    """Copy one emission record to its host array leaves — the wire
+    representation of a record (``results`` replies ship exactly these).
+
+    Deterministic walk: tuples/lists in order, dicts and object
+    ``__dict__``s by sorted key — so a remote consumer sees the SAME leaf
+    sequence an in-process consumer flattening the same record would (the
+    bit-identity contract tests/test_server.py pins).  Summary objects
+    (e.g. connected components' ``DisjointSet``) are plain host wrappers,
+    not registered pytrees, so ``jax.tree.leaves`` alone would return them
+    opaque — their array attributes are what travels.  Anything that would
+    land as a pickled object array is refused loudly instead (the wire
+    carries arrays, never code).
+
+    A record object with an ``on_host(arrays)`` method (the window
+    triangle count's) copies more than travels: its leaves come to the
+    host in the same copy, and the method returns the leaves that travel
+    and the window's counters, which tag the copy's span.
+    """
+    import jax
+
+    out: list = []
+    split: list = []
+    _collect_leaves(rec, out, jax.Array, split)
+    # the wait for the device to compute the leaves, apart from their copy
+    # to host: a long wait is the device still busy, a long copy the host
+    # moving bytes while the device may sit idle
+    with tracing.span("gelly.sink.wait"):
+        jax.block_until_ready([x for x in out if isinstance(x, jax.Array)])
+    with tracing.span("gelly.sink.copy") as copy:
+        host = [_host_leaf(x) for x in out]
+        for start, stop, owner in reversed(split):
+            leaves, counters = owner.on_host(host[start:stop])
+            host[start:stop] = leaves
+            copy.tag(**counters)
+        return host
+
+
+def _collect_leaves(x, out: list, device_array, split: list) -> None:
+    """The record walk, appending leaves to ``out`` as they are (device
+    arrays stay on the device), and to ``split`` the leaf range of each
+    object with an ``on_host`` method.  A module-level function, not a
+    closure: a recursive closure is a reference cycle, which would keep
+    every record's device leaves alive until the collector ran."""
+    if isinstance(x, (tuple, list)):
+        for item in x:
+            _collect_leaves(item, out, device_array, split)
+        return
+    if isinstance(x, dict):
+        for key in sorted(x):
+            _collect_leaves(x[key], out, device_array, split)
+        return
+    if isinstance(x, (np.ndarray, np.generic, int, float, bool, device_array)):
+        out.append(x)
+        return
+    state = getattr(x, "__dict__", None)
+    if state:
+        start = len(out)
+        for key in sorted(state):
+            _collect_leaves(state[key], out, device_array, split)
+        if hasattr(x, "on_host"):
+            split.append((start, len(out), x))
+        return
+    out.append(x)  # converted, or refused, by the host copy
+
+
+def _host_leaf(x) -> np.ndarray:
+    """One walked leaf on the host: a leaf that is not an array already
+    and has no array representation is refused."""
+    arr = np.asarray(x)
+    if arr.dtype == object and not isinstance(x, np.ndarray):
+        raise TypeError(
+            f"record leaf of type {type(x).__name__} has no array "
+            "representation; the results wire format carries arrays only"
+        )
+    return arr

@@ -487,7 +487,7 @@ class FoldRequest(NamedTuple):
     """One job's parked window fold, offered to a cross-tenant cohort.
 
     The fused-dispatch handshake record (core/aggregation.py
-    ``_fused_pane_records`` yields these; runtime/manager.py collects them):
+    ``_fused_fold`` yields these; runtime/manager.py collects them):
     ``key`` identifies the shared executable + padded shape, so requests with
     equal keys from different jobs can stack into one vmapped mega-fold.
     The arrays are already pow2-padded host arrays of length ``e_pad`` —

@@ -54,6 +54,7 @@ from typing import Callable, Dict, Iterator, List, Optional
 # lock-order: manager._lock < metrics._SCALE_LOCK
 # lock-order: manager._lock < metrics._HIST_LOCK
 
+from gelly_streaming_tpu.core import output
 from gelly_streaming_tpu.core.config import RuntimeConfig
 from gelly_streaming_tpu.core.windows import FoldRequest, stack_fold_rows
 from gelly_streaming_tpu.runtime.job import (
@@ -78,25 +79,12 @@ _FRESH = object()
 _DEVICE_AHEAD = 2
 
 
-def _device_leaves(rec, depth: int = 2) -> list:
-    """The device arrays in ``rec``: its pytree leaves, and those among the
-    attributes of host wrapper objects (a summary record such as connected
-    components' ``DisjointSet`` is not a pytree), ``depth`` objects deep."""
-    import jax
-
-    out = []
-    for leaf in jax.tree_util.tree_leaves(rec):
-        if isinstance(leaf, jax.Array):
-            out.append(leaf)
-        elif depth and isinstance(getattr(leaf, "__dict__", None), dict):
-            out += _device_leaves(vars(leaf), depth - 1)
-    return out
-
-
 def _computed(rec) -> bool:
     """True once every device array in ``rec`` is computed; never blocks.
     A deleted array holds no buffer (and is_ready() must not see it)."""
-    return all(x.is_deleted() or x.is_ready() for x in _device_leaves(rec))
+    return all(
+        x.is_deleted() or x.is_ready() for x in output.device_leaves(rec)
+    )
 
 
 def _device_behind(job: Job) -> bool:  # single-thread: scheduler

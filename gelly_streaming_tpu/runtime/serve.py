@@ -252,13 +252,9 @@ def main(argv=None) -> int:
         autoscale_policy=policy,
     )
 
-    def sink(rec):
-        # the serving sink: materialize every device leaf to host (a real
-        # frontend would serialize the record out here)
-        import jax
-
-        for leaf in jax.tree.leaves(rec):
-            np.asarray(leaf)
+    # the serving sink: copy every record to its host leaves, as the
+    # served path's results do (a real frontend would serialize them out)
+    from gelly_streaming_tpu.core.output import record_leaves as sink
 
     # per-job checkpoints: an explicit per-job "checkpoint" wins; otherwise
     # a top-level "checkpoint_prefix" keys one file per job name (the

@@ -50,6 +50,7 @@ from gelly_streaming_tpu.core.config import (
     StreamConfig,
     TenantConfig,
 )
+from gelly_streaming_tpu.core.output import record_leaves
 
 # The serving plane's slice of the sanctioned global lock order (pass
 # #7): the re-entrant admission serialization is the OUTERMOST lock of
@@ -172,78 +173,6 @@ def descriptor_for(query: str, spec: Optional[dict] = None):
         f"unknown query {query!r} (expected cc/degree/edges/triangles or "
         f"a sketch kind: {'/'.join(sketches.SKETCH_KINDS)})",
     )
-
-
-def record_leaves(rec) -> list:
-    """Flatten one emission record to its host array leaves — the wire
-    representation of a record (``results`` replies ship exactly these).
-
-    Deterministic walk: tuples/lists in order, dicts and object
-    ``__dict__``s by sorted key — so a remote consumer sees the SAME leaf
-    sequence an in-process consumer flattening the same record would (the
-    bit-identity contract tests/test_server.py pins).  Summary objects
-    (e.g. connected components' ``DisjointSet``) are plain host wrappers,
-    not registered pytrees, so ``jax.tree.leaves`` alone would return them
-    opaque — their array attributes are what travels.  Anything that would
-    land as a pickled object array is refused loudly instead (the wire
-    carries arrays, never code).
-
-    A record object with an ``on_host(arrays)`` method (the window
-    triangle count's) copies more than travels: its leaves come to the
-    host in the same copy, and the method returns the leaves that travel
-    and the window's counters, which tag the copy's span.
-    """
-    import jax
-
-    out: list = []
-    split: list = []
-    _collect_leaves(rec, out, jax.Array, split)
-    # the wait for the device to compute the leaves, apart from their copy
-    # to host: a long wait is the device still busy, a long copy the host
-    # moving bytes while the device may sit idle
-    with tracing.span("gelly.sink.wait"):
-        jax.block_until_ready([x for x in out if isinstance(x, jax.Array)])
-    with tracing.span("gelly.sink.copy") as copy:
-        host = [np.asarray(x) for x in out]
-        for start, stop, owner in reversed(split):
-            leaves, counters = owner.on_host(host[start:stop])
-            host[start:stop] = leaves
-            copy.tag(**counters)
-        return host
-
-
-def _collect_leaves(x, out: list, device_array, split: list) -> None:
-    """``record_leaves``' walk, appending leaves to ``out`` as they are
-    (device arrays stay on the device), and to ``split`` the leaf range
-    of each object with an ``on_host`` method.  A module-level function,
-    not a closure: a recursive closure is a reference cycle, which would
-    keep every record's device leaves alive until the collector ran."""
-    if isinstance(x, (tuple, list)):
-        for item in x:
-            _collect_leaves(item, out, device_array, split)
-        return
-    if isinstance(x, dict):
-        for key in sorted(x):
-            _collect_leaves(x[key], out, device_array, split)
-        return
-    if isinstance(x, (np.ndarray, np.generic, int, float, bool, device_array)):
-        out.append(x)
-        return
-    state = getattr(x, "__dict__", None)
-    if state:
-        start = len(out)
-        for key in sorted(state):
-            _collect_leaves(state[key], out, device_array, split)
-        if hasattr(x, "on_host"):
-            split.append((start, len(out), x))
-        return
-    arr = np.asarray(x)
-    if arr.dtype == object:
-        raise TypeError(
-            f"record leaf of type {type(x).__name__} has no array "
-            "representation; the results wire format carries arrays only"
-        )
-    out.append(arr)
 
 
 class _TokenBucket:
@@ -1598,9 +1527,7 @@ class StreamServer:
         w = cfg.ingest_window_edges
         if not (checkpoint_path and w):
             return 0
-        last_window, _gdone = descriptor._restored_position(
-            cfg, checkpoint_path, True
-        )
+        last_window, _gdone = descriptor.restored_position(cfg, checkpoint_path)
         return (last_window + 1) * w
 
     def _make_push_source(self, cfg, resume_edges: int):
@@ -1790,12 +1717,10 @@ class StreamServer:
             if job is not None and not job._state_in(*JobState.TERMINAL):
                 self.manager.cancel(job, wait=True, timeout=60.0)
             cursor = None
-            w = sj.cfg.ingest_window_edges
-            if sj.checkpoint_path and w:
-                last_window, _gdone = sj.descriptor._restored_position(
-                    sj.cfg, sj.checkpoint_path, True
+            if sj.checkpoint_path and sj.cfg.ingest_window_edges:
+                cursor = self._resume_cursor(
+                    sj.descriptor, sj.cfg, sj.checkpoint_path
                 )
-                cursor = (last_window + 1) * w
             cursors[sj.name] = {
                 "resume_edges": cursor,
                 "checkpoint": bool(sj.checkpoint_path),

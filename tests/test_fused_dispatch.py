@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from gelly_streaming_tpu.core import compile_cache
 from gelly_streaming_tpu.core.aggregation import SummaryBulkAggregation
 from gelly_streaming_tpu.core.config import RuntimeConfig, StreamConfig
+from gelly_streaming_tpu.core.output import record_leaves
 from gelly_streaming_tpu.core.stream import EdgeStream
 from gelly_streaming_tpu.core.windows import FoldRequest
 from gelly_streaming_tpu.library.connected_components import (
@@ -439,6 +440,68 @@ def test_run_fused_protocol_and_solo_fallback_oracle():
         except StopIteration:
             break
     _assert_windows_equal(want, got, "protocol")
+
+
+class TransientEdgeCount(EdgeCount):
+    """Per-window edge counts: the Merger resets after each emission."""
+
+    transient_state = True
+
+
+def _drive_fused(gen, stop_after=None):
+    """Run one cohort member alone: even windows take a one-row fused
+    partial from the request's own executables, odd ones the solo
+    fallback.  With ``stop_after``, the consumer dies holding that many
+    records (the generator is closed at its last yield: a crash between
+    the emission and its snapshot)."""
+    from gelly_streaming_tpu.core.windows import stack_fold_rows
+
+    out, reply = [], None
+    while True:
+        try:
+            item = gen.send(reply)
+        except StopIteration:
+            return out
+        reply = None
+        if isinstance(item, FoldRequest):
+            if item.window_id % 2 == 0:
+                src, dst, _val, msk, pad = stack_fold_rows([item])
+                states = item.fold(
+                    jnp.asarray(src), jnp.asarray(dst), None, jnp.asarray(msk)
+                )
+                reply = item.split(1 + pad)(states)[0]
+            continue
+        out.append(record_leaves(item))
+        if len(out) == stop_after:
+            gen.close()
+            return out
+
+
+@pytest.mark.parametrize(
+    "make", [ConnectedComponents, TransientEdgeCount], ids=["cc", "transient"]
+)
+def test_fused_resume_after_crash_matches_solo(tmp_path, make):
+    """The fused plane's positional checkpoints: a consumer that dies
+    holding window 3's record (before its snapshot) and a resume from the
+    snapshot give an uninterrupted solo run's records — window 3 again
+    (at-least-once emission), every other window once."""
+    s, d = _graph(17, 8 * WIN)
+    want = [
+        record_leaves(rec)
+        for rec in EdgeStream.from_arrays(s, d, CFG_SOLO).aggregate(make())
+    ]
+    path = str(tmp_path / "fused")
+
+    def run():
+        stream = EdgeStream.from_arrays(s, d, CFG_FUSED)
+        return make().run_fused(stream, checkpoint_path=path)
+
+    first = _drive_fused(run(), stop_after=4)
+    resumed = _drive_fused(run())
+    got = first + resumed
+    assert len(want) == 8 and len(got) == len(want) + 1
+    for w, (a, b) in enumerate(zip(want[:4] + want[3:], got)):
+        assert all(np.array_equal(x, y) for x, y in zip(a, b)), f"record {w}"
 
 
 def test_fused_dispatch_stats_exposed():

@@ -740,7 +740,8 @@ def test_computed_reads_device_readiness():
     and inside host wrapper objects (a summary record such as
     ``DisjointSet`` is not a pytree); computed and deleted arrays and host
     leaves count as finished (a deleted array is never probed)."""
-    from gelly_streaming_tpu.runtime.manager import _computed, _device_leaves
+    from gelly_streaming_tpu.core.output import device_leaves
+    from gelly_streaming_tpu.runtime.manager import _computed
 
     class Summary:
         def __init__(self, **attrs):
@@ -748,11 +749,66 @@ def test_computed_reads_device_readiness():
 
     parent, seen = jnp.arange(4), jnp.ones(3)
     rec = (7, Summary(parent=parent, extra={"seen": seen, "n": 3}), np.zeros(2), None)
-    assert {id(x) for x in _device_leaves(rec)} == {id(parent), id(seen)}
+    assert {id(x) for x in device_leaves(rec)} == {id(parent), id(seen)}
     gone = jnp.arange(4)
     gone.delete()
     assert _computed(rec)
     assert _computed({"summary": gone, "wrapped": Summary(parent=parent)})
+
+
+def _walk_case(kind):
+    """(record, its device leaves in wire order) for one record shape."""
+    if kind == "cc":
+        from gelly_streaming_tpu.summaries.disjoint_set import DisjointSet
+
+        parent, seen = jnp.arange(8), jnp.ones(8, bool)
+        return (DisjointSet(8, parent=parent, seen=seen),), [parent, seen]
+    if kind == "triangles":
+        from gelly_streaming_tpu.library.triangles import WindowTriangles
+        from gelly_streaming_tpu.ops import oriented_triangles
+
+        stats = jnp.zeros(len(oriented_triangles.STATS), jnp.int32)
+        return (WindowTriangles(stats),), [stats]
+    a, b, c = jnp.arange(3), jnp.ones(2), jnp.zeros(4, jnp.int32)
+    return ({"b": b, "a": a}, (c, np.zeros(2), 3)), [a, b, c]
+
+
+@pytest.mark.parametrize("kind", ["cc", "triangles", "tree"])
+def test_one_record_walk_for_probe_fetch_and_copy(kind, monkeypatch):
+    """The scheduler's in-flight probe, the async plane's host fetch and
+    the host copy that makes the wire leaves all see a record's device
+    leaves through one walk: the same arrays, in the same (wire) order —
+    through a ``DisjointSet`` wrapper and a ``WindowTriangles`` record
+    with ``on_host``, as through plain dicts and tuples."""
+    import jax
+
+    from gelly_streaming_tpu.core import async_exec, output
+    from gelly_streaming_tpu.runtime.manager import _computed
+
+    rec, want = _walk_case(kind)
+    seen = {}
+    walk, wait = output.device_leaves, jax.block_until_ready
+
+    def spy_walk(r):
+        seen.setdefault("walk", []).append([id(x) for x in walk(r)])
+        return walk(r)
+
+    def spy_wait(xs):
+        seen["copy"] = [id(x) for x in xs]
+        return wait(xs)
+
+    monkeypatch.setattr(output, "device_leaves", spy_walk)
+    monkeypatch.setattr(jax, "block_until_ready", spy_wait)
+    assert _computed(rec)
+    async_exec.start_host_fetch(rec)
+    host = output.record_leaves(rec)
+    ids = [id(x) for x in want]
+    assert seen["walk"] == [ids, ids]  # the probe, then the fetch
+    assert seen["copy"] == ids
+    if kind == "tree":
+        assert [np.asarray(x).tolist() for x in host] == [
+            np.asarray(x).tolist() for x in (want + [np.zeros(2), 3])
+        ]
 
 
 # ---------------------------------------------------------------------------
@@ -901,9 +957,18 @@ def test_sigkill_two_jobs_resume_from_independent_checkpoints(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_serve_main_runs_jobs_to_done(capsys):
+def test_serve_main_runs_jobs_to_done(capsys, monkeypatch):
+    from gelly_streaming_tpu.core import output
     from gelly_streaming_tpu.runtime import serve
 
+    copied = []
+    copy = output.record_leaves
+
+    def spy(rec):
+        copied.append(copy(rec))
+        return copied[-1]
+
+    monkeypatch.setattr(output, "record_leaves", spy)
     rc = serve.main(
         [
             "--jobs",
@@ -924,3 +989,8 @@ def test_serve_main_runs_jobs_to_done(capsys):
     out = capsys.readouterr()
     assert "2 job(s)" in out.out
     assert "DONE" in out.err
+    # the synthetic sink copies each cc record's parent and seen arrays
+    assert copied and all(
+        [leaf.shape for leaf in leaves] == [(), (4096,), (4096,)]
+        for leaves in copied
+    )

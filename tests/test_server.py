@@ -234,6 +234,7 @@ def test_served_cc_cuts_panes_without_pipeline_step(monkeypatch):
 
     from gelly_streaming_tpu.core.stream import EdgeStream
 
+    from gelly_streaming_tpu.core.output import device_leaves
     from gelly_streaming_tpu.runtime import manager as mgr
 
     stepped, probed = [], []
@@ -244,7 +245,7 @@ def test_served_cc_cuts_panes_without_pipeline_step(monkeypatch):
         return orig_step(self)
 
     def spy_computed(rec):
-        probed.append(len(mgr._device_leaves(rec)))
+        probed.append(len(device_leaves(rec)))
         return orig_computed(rec)
 
     monkeypatch.setattr(EdgeStream, "_compiled_step", spy_step)
