@@ -35,9 +35,60 @@ from gelly_streaming_tpu.parallel.mesh import SHARD_AXIS
 from gelly_streaming_tpu.summaries.disjoint_set import DisjointSet
 
 
+# The counters of a summary that no fold wrote (initial, combined, gathered).
+_NO_FOLD = np.zeros((len(uf.UF_STATS),), np.int32)
+_NO_FOLD.setflags(write=False)
+
+# What a snapshot of a CCState holds, under the name (and so the layout)
+# that CC snapshots have always had: the fold's counters never persist.
+_CCSnapshot = NamedTuple(
+    "CCState", [("parent", jax.Array), ("seen", jax.Array)]
+)
+
+
 class CCState(NamedTuple):
+    """The CC summary: ``parent`` is always fully compressed (each entry
+    its component's minimum vertex id), the precondition of the window
+    fold (``uf.fold_roots``).  ``uf_stats`` holds the counters of the fold
+    that wrote it (``uf.UF_STATS``); they reach the host with the window's
+    record and are not part of a snapshot."""
+
     parent: jax.Array  # int32[C]
     seen: jax.Array  # bool[C]
+    uf_stats: jax.Array = _NO_FOLD  # int32[len(uf.UF_STATS)]
+
+    def snapshot(self):
+        """The leaves a snapshot keeps (``utils/checkpoint.py``)."""
+        return _CCSnapshot(self.parent, self.seen)
+
+    def from_snapshot(self, snap) -> "CCState":
+        return CCState(snap.parent, snap.seen, self.uf_stats)
+
+
+class WindowComponents(DisjointSet):
+    """One record of a CC job: the ``DisjointSet`` that travels, plus the
+    counters of the fold that wrote it (``uf_stats``, on the device until
+    the sink copies it), which come to the host in the same copy and tag
+    its span."""
+
+    def __init__(self, capacity: int, parent, seen, uf_stats):
+        super().__init__(capacity=capacity, parent=parent, seen=seen)
+        self.uf_stats = uf_stats
+
+    def on_host(self, arrays):
+        """``record_leaves``' hook once the copy is on the host: the
+        leaves that travel (the ``DisjointSet``'s, so the wire record is
+        unchanged), and the counters (added to ``pipeline_stats``)."""
+        from gelly_streaming_tpu.utils import metrics
+
+        *leaves, stats = arrays  # attributes walk in sorted order
+        counters = {
+            f"uf_{name}": int(v) for name, v in zip(uf.UF_STATS, stats)
+        }
+        metrics.pipeline_add("uf_windows", 1)
+        for key, value in counters.items():
+            metrics.pipeline_add(key, value)
+        return leaves, counters
 
 
 class _CCMixin:
@@ -58,27 +109,29 @@ class _CCMixin:
         return CCState(
             parent=uf.init_parent(cfg.vertex_capacity),
             seen=jnp.zeros((cfg.vertex_capacity,), bool),
+            uf_stats=jnp.asarray(_NO_FOLD),
         )
 
     def update(self, state: CCState, src, dst, val, mask) -> CCState:
         # UpdateCC.foldEdges == ds.union(src, trg) (ConnectedComponents.java:83-86)
-        parent, seen = uf.union_edges_with_seen(
-            state.parent, state.seen, src, dst, mask
+        return CCState(
+            *uf.union_edges_with_seen(state.parent, state.seen, src, dst, mask)
         )
-        return CCState(parent, seen)
 
     def combine(self, a: CCState, b: CCState) -> CCState:
         # CombineCC.reduce == DisjointSet.merge (ConnectedComponents.java:116-124)
         return CCState(
             parent=uf.merge_parents(a.parent, b.parent),
             seen=a.seen | b.seen,
+            uf_stats=jnp.asarray(_NO_FOLD),
         )
 
-    def transform(self, state: CCState) -> DisjointSet:
-        return DisjointSet(
+    def transform(self, state: CCState) -> WindowComponents:
+        return WindowComponents(
             capacity=int(state.parent.shape[0]),
             parent=state.parent,
             seen=state.seen,
+            uf_stats=state.uf_stats,
         )
 
     def mesh_combine_states(self, cfg: StreamConfig, axis_name: str):
@@ -89,7 +142,8 @@ class _CCMixin:
             return CCState(
                 *collective_parent_seen_combine(
                     state.parent, state.seen, axis_name
-                )
+                ),
+                uf_stats=jnp.asarray(_NO_FOLD),
             )
 
         return combine
@@ -275,7 +329,7 @@ class CCShardedState(ShardedStateSpec):
             blk, _, rounds, hwm, spills = c
             full = routing.gather_blocks(blk, n, axis)  # gather-ok: exchange reconciliation round (emit/snapshot boundary)
             base = uf.compress(full)
-            p2 = uf.union_edges(base, v, local_p)
+            p2, _ = uf.fold_roots(base, v, local_p)
             # the delta: OLD ROOT rows that remapped — bounded by merges
             # since the last exchange, never by component sizes
             changed = (base == v) & (p2 != v)
@@ -338,7 +392,9 @@ class CCShardedState(ShardedStateSpec):
         full = routing.gather_blocks(blocks.label, ctx.num_shards, ctx.axis_name)  # gather-ok: emit — lazy replicated view at emission/snapshot boundaries
         seen = routing.gather_blocks(blocks.seen, ctx.num_shards, ctx.axis_name)  # gather-ok: emit — lazy replicated view at emission/snapshot boundaries
         # fully compress so the emitted labels are the oracle's min labels
-        return CCState(parent=uf.compress(full), seen=seen)
+        return CCState(
+            parent=uf.compress(full), seen=seen, uf_stats=jnp.asarray(_NO_FOLD)
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,10 @@ The reference's streaming Connected Components folds every edge through a
 pointer-chasing, recursively path-compressing ``DisjointSet``
 (summaries/DisjointSet.java:66-118) — inherently sequential, one edge at a time.
 The TPU-native replacement operates on a dense ``parent: int32[C]`` array and
-processes a whole edge micro-batch with scatter-min *hooking* plus
-pointer-doubling *compression* (Shiloach–Vishkin style), converging to the same
-fixed point: ``parent[v]`` is the minimum vertex id in v's component.
+processes a whole edge micro-batch with scatter-min *hooking* of the batch's
+endpoint roots (Shiloach–Vishkin style) and one pointer-doubling pass,
+converging to the same fixed point: ``parent[v]`` is the minimum vertex id in
+v's component.
 
 All functions are pure and jittable; state threads through functionally.
 """
@@ -22,6 +23,13 @@ import jax.numpy as jnp
 def init_parent(capacity: int) -> jax.Array:
     """Every vertex its own singleton root."""
     return jnp.arange(capacity, dtype=jnp.int32)
+
+
+# The counters of one union-find fold, in the order of its ``stats`` vector:
+# hook rounds; whole-array (capacity-long) passes, which is 1 when some round
+# hooked and 0 otherwise (derived from the rounds, not counted apart); and
+# pointer-jumping steps, each a gather over a round's hooked roots.
+UF_STATS = ("hook_rounds", "full_passes", "root_steps")
 
 
 def compress(parent: jax.Array) -> jax.Array:
@@ -44,16 +52,93 @@ def compress(parent: jax.Array) -> jax.Array:
     return p
 
 
-def find_roots(parent: jax.Array, vertices: jax.Array) -> jax.Array:
-    """Chase parent pointers for a vector of vertices (no mutation)."""
+def jump_hooked(parent: jax.Array, hooked: jax.Array):
+    """Point every vertex of ``hooked`` at its root, by pointer jumping
+    over ``hooked`` alone; returns the parent and the steps made (gathers
+    over ``hooked``).
 
-    def cond(r):
-        return jnp.any(parent[r] != r)
+    ``hooked`` must hold every non-root vertex its entries reach (as a
+    hook round's ``hi`` array does: the vertices it hooked, each onto a
+    root or another of them), so ``p[h] <- p[p[h]]`` over it halves every
+    chain: log2 of the longest chain steps, each a gather and a scatter
+    over ``hooked``, where a plain chase takes one gather per level.
+    """
+    up = parent[hooked]
 
-    def body(r):
-        return parent[r]
+    def cond(state):
+        _, up, upup, _ = state
+        return jnp.any(upup != up)
 
-    return jax.lax.while_loop(cond, body, vertices)
+    def body(state):
+        p, _, upup, steps = state
+        p = p.at[hooked].set(upup)
+        return p, upup, p[upup], steps + 1
+
+    p, _, _, steps = jax.lax.while_loop(
+        cond, body, (parent, up, parent[up], jnp.int32(2))
+    )
+    return p, steps
+
+
+def fold_roots(
+    parent: jax.Array,
+    src: jax.Array,
+    dst: jax.Array,
+    mask: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Merge the components of every valid (src, dst) edge into a FULLY
+    COMPRESSED ``parent`` (each entry its root); returns the parent, again
+    fully compressed, and the fold's counters (``UF_STATS``).
+
+    Window-local: the work scales with the batch, plus at most one pass
+    over the whole array.
+
+      entry:  read the endpoints' roots once (the 2E "slots")
+      round:  while some edge's slot roots differ, hook
+              parent[max(root_s, root_d)] <- min over edges (scatter-min),
+              point the hooked roots at their roots by pointer jumping
+              over them (``jump_hooked``), then gather the slots' roots
+      exit:   if anything hooked, point every entry slot root at its final
+              root, then one pass parent <- parent[parent]
+
+    Only the entry slot roots are ever hooked (a hook writes a slot root
+    onto a slot root, and resolving one stays among them), so after the
+    exit write every vertex is within two steps of its root and the one
+    pass compresses fully.  The hooks are those of hooking then fully
+    compressing every round, so the fixed point is the same array, bit for
+    bit: each entry the minimum root of its component.  Masked rows are
+    turned into self-loops and cannot affect state.
+    """
+    if mask is not None:
+        src = jnp.where(mask, src, 0)
+        dst = jnp.where(mask, dst, 0)
+    e = src.shape[0]
+    roots0 = parent[jnp.concatenate([src, dst])]
+
+    def unjoined(state):
+        r = state[1]
+        return jnp.any(r[:e] != r[e:])
+
+    def hook(state):
+        p, r, rounds, steps = state
+        rs, rd = r[:e], r[e:]
+        hi = jnp.maximum(rs, rd)
+        p, n = jump_hooked(p.at[hi].min(jnp.minimum(rs, rd)), hi)
+        return p, p[r], rounds + 1, steps + n
+
+    zero = jnp.int32(0)
+    p, roots, rounds, steps = jax.lax.while_loop(
+        unjoined, hook, (parent, roots0, zero, zero)
+    )
+
+    def settle(p):
+        # duplicate slot roots carry the same final root
+        p = p.at[roots0].set(roots)
+        return p[p]
+
+    hooked = rounds > 0
+    p = jax.lax.cond(hooked, settle, lambda p: p, p)
+    return p, jnp.stack([rounds, hooked.astype(jnp.int32), steps])
 
 
 def union_edges(
@@ -65,30 +150,11 @@ def union_edges(
     """Merge the components of every valid (src, dst) edge in the batch.
 
     Equivalent fixed point to folding each edge through DisjointSet.union
-    (summaries/DisjointSet.java:92-118), but order-free and batched:
-
-      repeat until all edges have equal endpoint roots:
-        hook:     parent[max(root_s, root_d)] <- min over edges (scatter-min)
-        compress: full pointer doubling
-
-    Masked rows are turned into self-loops and cannot affect state.
+    (summaries/DisjointSet.java:92-118), but order-free and batched.  Any
+    parent-pointer forest: it is fully compressed first, then folded by
+    ``fold_roots``.  Masked rows cannot affect state.
     """
-    if mask is not None:
-        src = jnp.where(mask, src, 0)
-        dst = jnp.where(mask, dst, 0)
-
-    def cond(p):
-        return jnp.any(p[src] != p[dst])
-
-    def body(p):
-        rs = p[src]
-        rd = p[dst]
-        lo = jnp.minimum(rs, rd)
-        hi = jnp.maximum(rs, rd)
-        p = p.at[hi].min(lo)
-        return compress(p)
-
-    return jax.lax.while_loop(cond, body, compress(parent))
+    return fold_roots(compress(parent), src, dst, mask)[0]
 
 
 def merge_parents(parent_a: jax.Array, parent_b: jax.Array) -> jax.Array:
@@ -103,25 +169,36 @@ def merge_parents(parent_a: jax.Array, parent_b: jax.Array) -> jax.Array:
     return union_edges(parent_a, v, parent_b, mask=None)
 
 
+def mark_seen(
+    seen: jax.Array,
+    src: jax.Array,
+    dst: jax.Array,
+    mask: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Mark every valid edge's endpoints as seen.
+
+    ``seen`` distinguishes real components from untouched identity entries when
+    enumerating components (DisjointSet's map only contains added elements,
+    DisjointSet.java:40-46; a dense array must track membership explicitly).
+    """
+    if mask is None:
+        mask = jnp.ones(src.shape, bool)
+    seen = seen.at[jnp.where(mask, src, 0)].max(mask)
+    return seen.at[jnp.where(mask, dst, 0)].max(mask)
+
+
 def union_edges_with_seen(
     parent: jax.Array,
     seen: jax.Array,
     src: jax.Array,
     dst: jax.Array,
     mask: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """union_edges plus tracking of which vertices have appeared.
-
-    ``seen`` distinguishes real components from untouched identity entries when
-    enumerating components (DisjointSet's map only contains added elements,
-    DisjointSet.java:40-46; a dense array must track membership explicitly).
-    """
-    parent = union_edges(parent, src, dst, mask)
-    if mask is None:
-        mask = jnp.ones(src.shape, bool)
-    seen = seen.at[jnp.where(mask, src, 0)].max(mask)
-    seen = seen.at[jnp.where(mask, dst, 0)].max(mask)
-    return parent, seen
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The summary fold: ``fold_roots`` into a fully compressed ``parent``
+    (every summary this package makes is one) plus ``mark_seen``; returns
+    the parent, ``seen`` and the fold's counters (``UF_STATS``)."""
+    parent, stats = fold_roots(parent, src, dst, mask)
+    return parent, mark_seen(seen, src, dst, mask), stats
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,11 @@ _compress_j = jax.jit(uf.compress)  # graft: disable=RAWJIT — pinned for proce
 
 
 class DisjointSet:
-    """Host wrapper over (parent, seen) arrays; one component = one min-root."""
+    """Host wrapper over (parent, seen) arrays; one component = one min-root.
+
+    ``parent`` is fully compressed (each entry its root), as every summary
+    this package makes is: the batched union folds into it window-locally
+    (``uf.union_edges_with_seen``) and keeps it so."""
 
     def __init__(self, capacity: int, parent=None, seen=None):
         self.capacity = capacity
@@ -50,7 +54,7 @@ class DisjointSet:
 
     def union_batch(self, src, dst, mask: Optional[jnp.ndarray] = None) -> None:
         """Batched union of a whole edge micro-batch (the TPU hot path)."""
-        self.parent, self.seen = _union_edges_seen_j(
+        self.parent, self.seen, _ = _union_edges_seen_j(
             self.parent, self.seen, src, dst, mask
         )
 

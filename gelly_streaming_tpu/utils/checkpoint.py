@@ -26,10 +26,26 @@ def _normalize(path: str) -> str:
     return path if path.endswith(".npz") else path + ".npz"
 
 
+def _keeps_part(node: Any) -> bool:
+    """A state node whose snapshot leaves some of its leaves out (such as
+    a fold's counters): it has ``snapshot()`` and ``from_snapshot(snap)``."""
+    return hasattr(node, "snapshot") and hasattr(node, "from_snapshot")
+
+
+def _snapshot_view(state: Any) -> Any:
+    """The pytree a snapshot of ``state`` holds."""
+    return jax.tree.map(
+        lambda x: x.snapshot() if _keeps_part(x) else x,
+        state,
+        is_leaf=_keeps_part,
+    )
+
+
 def save_state(path: str, state: Any) -> None:
     """Snapshot any pytree-of-arrays state to ``path`` (.npz), atomically:
     a crash mid-save must never destroy the previous good snapshot."""
     path = _normalize(path)
+    state = _snapshot_view(state)
     leaves, treedef = jax.tree.flatten(state)
     arrays = {f"leaf_{i}": np.asarray(leaf) for i, leaf in enumerate(leaves)}
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
@@ -45,18 +61,20 @@ def checkpoint_exists(path: str) -> bool:
 
 
 def load_state(path: str, like: Any) -> Any:
-    """Restore a snapshot into the structure of ``like`` (same pytree shape)."""
+    """Restore a snapshot into the structure of ``like`` (same pytree shape);
+    the leaves a node's snapshot leaves out come from ``like``."""
     path = _normalize(path)
+    view = _snapshot_view(like)
     with np.load(path) as data:
-        leaves_like, treedef = jax.tree.flatten(like)
+        leaves_like, treedef = jax.tree.flatten(view)
         n = len(leaves_like)
         # validate the layout BEFORE touching leaves, so a structure change
         # (e.g. a legacy snapshot) surfaces as ValueError, not KeyError
         token = json.loads(bytes(data["__treedef__"]).decode())
-        if token != _treedef_token(like):
+        if token != _treedef_token(view):
             raise ValueError(
                 f"checkpoint structure mismatch: stored {token}, "
-                f"expected {_treedef_token(like)}"
+                f"expected {_treedef_token(view)}"
             )
         stored = [data[f"leaf_{i}"] for i in range(n)]
     # numpy leaves (host-side metadata like stream positions) restore as
@@ -68,7 +86,12 @@ def load_state(path: str, like: Any) -> Any:
         else jax.numpy.asarray(s, dtype=l.dtype)
         for s, l in zip(stored, leaves_like)
     ]
-    return jax.tree.unflatten(treedef, restored)
+    return jax.tree.map(
+        lambda node, snap: node.from_snapshot(snap) if _keeps_part(node) else snap,
+        like,
+        jax.tree.unflatten(treedef, restored),
+        is_leaf=_keeps_part,
+    )
 
 
 def _treedef_token(state: Any):

@@ -159,6 +159,12 @@ def _pipeline_zero() -> dict:
         "tri_wedges": 0,
         "tri_slots": 0,
         "tri_bucket_high_water": 0,
+        # the served cc fold's per-window counters (ops/unionfind.UF_STATS),
+        # summed over the records copied to the host
+        "uf_windows": 0,
+        "uf_hook_rounds": 0,
+        "uf_full_passes": 0,
+        "uf_root_steps": 0,
     }
 
 
@@ -186,8 +192,9 @@ def pipeline_stats() -> dict:
     window high-water mark, per-stage stall seconds (pack / transfer /
     dispatch / drain), prefetcher queue depth, dispatched/drained window
     counts, the Merger's panes by route (``merge_running_folds`` /
-    ``merge_combines`` / ``merge_transient_folds``) and the served triangle
-    count's per-window kernel counters (``tri_*``).  Reported by bench.py
+    ``merge_combines`` / ``merge_transient_folds``), the served triangle
+    count's per-window kernel counters (``tri_*``) and the cc fold's
+    (``uf_*``).  Reported by bench.py
     next to ``compile_cache_stats``."""
     with _PIPE_LOCK:
         out = dict(_PIPELINE)

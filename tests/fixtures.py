@@ -66,3 +66,32 @@ def kronecker(scale: int, edges: int, seed: int):
         dst |= (((u >= 0.57) & (u < 0.76)) | (u >= 0.95)).astype(np.int64) << level
     perm = rng.permutation(1 << scale)
     return perm[src].astype(np.int32), perm[dst].astype(np.int32)
+
+
+def rounds_union_edges(parent, src, dst, mask=None):
+    """The batched union-find fold as it stood before the window-local one,
+    kept as its reference: compress the whole array, then, while any
+    edge's endpoint roots differ, hook ``parent[max] <- min`` and compress
+    the whole array again.  Returns the parent and the hook rounds."""
+    import jax
+    import jax.numpy as jnp
+
+    from gelly_streaming_tpu.ops import unionfind as uf
+
+    if mask is not None:
+        src = jnp.where(mask, src, 0)
+        dst = jnp.where(mask, dst, 0)
+
+    def cond(state):
+        p = state[0]
+        return jnp.any(p[src] != p[dst])
+
+    def body(state):
+        p, rounds = state
+        rs, rd = p[src], p[dst]
+        p = p.at[jnp.maximum(rs, rd)].min(jnp.minimum(rs, rd))
+        return uf.compress(p), rounds + 1
+
+    return jax.lax.while_loop(
+        cond, body, (uf.compress(parent), jnp.int32(0))
+    )

@@ -8,6 +8,7 @@ analog)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from gelly_streaming_tpu.core.config import StreamConfig
@@ -99,3 +100,93 @@ def test_sharded_cc_matches_single_device():
         jnp.asarray(routed.mask),
     )
     np.testing.assert_array_equal(np.asarray(parent), single)
+
+
+# ---- every producer of a CC summary hands the fold a compressed parent -------
+
+_PRE_CAP = 64
+_PRE_CFG = StreamConfig(vertex_capacity=_PRE_CAP, batch_size=64)
+
+
+def _pre_edges(seed):
+    rng = np.random.default_rng(seed)
+    return (
+        jnp.asarray(rng.integers(0, _PRE_CAP, 48), jnp.int32),
+        jnp.asarray(rng.integers(0, _PRE_CAP, 48), jnp.int32),
+    )
+
+
+def _pre_folded(agg, seed):
+    state = agg.initial_state(_PRE_CFG)
+    for k in range(2):
+        src, dst = _pre_edges(seed + k)
+        state = agg.update(state, src, dst, None, jnp.ones((48,), bool))
+    return state
+
+
+def _pre_snapshot(agg, tmp_path):
+    from gelly_streaming_tpu.core.aggregation import MergerState
+
+    path = str(tmp_path / "cc.npz")
+    MergerState(agg, _PRE_CFG, path, restore=False).save(_pre_folded(agg, 1))
+    return MergerState(agg, _PRE_CFG, path).running
+
+
+def _pre_mesh(sharded_state, seed=3):
+    import dataclasses
+
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, _PRE_CAP, 300).astype(np.int32)
+    dst = rng.integers(0, _PRE_CAP, 300).astype(np.int32)
+    cfg = dataclasses.replace(_PRE_CFG, num_shards=8, sharded_state=sharded_state)
+    recs = EdgeStream.from_arrays(src, dst, cfg).aggregate(ConnectedComponents()).collect()
+    return recs[-1][0]
+
+
+_PRODUCERS = {
+    "initial_state": lambda agg, tmp: agg.initial_state(_PRE_CFG),
+    "update": lambda agg, tmp: _pre_folded(agg, 1),
+    "combine": lambda agg, tmp: agg.combine(_pre_folded(agg, 1), _pre_folded(agg, 5)),
+    "snapshot_restore": _pre_snapshot,
+    "sharded_emission": lambda agg, tmp: _pre_mesh(sharded_state=1),
+    "mesh_combine": lambda agg, tmp: _pre_mesh(sharded_state=0),
+}
+
+
+@pytest.mark.parametrize("producer", sorted(_PRODUCERS))
+def test_cc_summary_producers_return_a_compressed_parent(producer, tmp_path):
+    """The window fold (``uf.fold_roots``) takes the running summary's
+    parent as fully compressed, with no pass to make it so: every producer
+    of a CC summary or record returns ``parent[parent] == parent``."""
+    out = _PRODUCERS[producer](ConnectedComponents(), tmp_path)
+    p = np.asarray(out.parent)
+    np.testing.assert_array_equal(p[p], p)
+    assert (p <= np.arange(_PRE_CAP)).all()  # each entry its component's minimum
+
+
+def test_cc_snapshot_layout_is_unchanged(tmp_path):
+    """A CC snapshot holds (parent, seen) under the layout it always had:
+    the fold's counters stay out, a snapshot in that layout restores, and
+    the counters restore as no fold's."""
+    from typing import NamedTuple
+
+    from gelly_streaming_tpu.utils.checkpoint import load_state, save_state
+
+    class CCState(NamedTuple):  # the summary as every earlier snapshot holds it
+        parent: jax.Array
+        seen: jax.Array
+
+    agg = ConnectedComponents()
+    state = _pre_folded(agg, 1)
+    assert int(np.asarray(state.uf_stats)[0]) > 0  # the fold hooked
+    new, old = tmp_path / "new.npz", tmp_path / "old.npz"
+    save_state(str(new), {"summary": state})
+    save_state(str(old), {"summary": CCState(state.parent, state.seen)})
+    with np.load(new) as a, np.load(old) as b:
+        assert sorted(a.files) == sorted(b.files)
+        for key in a.files:
+            np.testing.assert_array_equal(a[key], b[key])
+    got = load_state(str(old), {"summary": agg.initial_state(_PRE_CFG)})["summary"]
+    np.testing.assert_array_equal(np.asarray(got.parent), np.asarray(state.parent))
+    np.testing.assert_array_equal(np.asarray(got.seen), np.asarray(state.seen))
+    assert np.asarray(got.uf_stats).tolist() == [0] * len(uf.UF_STATS)

@@ -271,8 +271,9 @@ def test_served_cc_cuts_panes_without_pipeline_step(monkeypatch):
         want_seen[s[:hi]] = want_seen[d[:hi]] = True
         np.testing.assert_array_equal(np.asarray(seen, bool), want_seen)
     assert () not in stepped, "a stage-less stream ran the pipeline step"
-    # the scheduler's in-flight bound sees each record's parent and seen
-    assert probed and set(probed) == {2}, probed
+    # the scheduler's in-flight bound sees each record's parent, seen and
+    # fold counters
+    assert probed and set(probed) == {3}, probed
 
 
 # ---------------------------------------------------------------------------

@@ -288,7 +288,7 @@ def test_cc_fixpoint_matches_unionfind():
         dst = rng.integers(0, C, 64).astype(np.int32)
         msk = rng.random(64) < 0.7
         p0, s0 = uf.init_parent(C), jnp.zeros((C,), bool)
-        p_want, s_want = uf.union_edges_with_seen(
+        p_want, s_want, _ = uf.union_edges_with_seen(
             p0, s0, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(msk)
         )
         p_got, s_got = spmv.cc_fixpoint(

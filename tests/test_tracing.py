@@ -411,6 +411,48 @@ def test_served_spans_share_job_and_window_on_the_profilers_clock(tmp_path):
     assert sorted(p["offset"] for p in pushes)[0] == 0
 
 
+@pytest.mark.timeout_cap(300)
+def test_served_cc_fold_counters_reach_stats_and_span_tags(tmp_path):
+    """A served cc job's per-window fold counters come to the host with
+    each record: summed into ``pipeline_stats`` (``uf_*``) and tagged on
+    the window's ``gelly.sink.copy`` span, while the record on the wire is
+    the plain ``DisjointSet``'s (capacity, parent, seen), bit for bit what
+    the hook-and-compress rounds fold."""
+    import jax.numpy as jnp
+
+    from fixtures import rounds_union_edges
+
+    metrics.reset_pipeline_stats()
+    n, w, cap = 1 << 14, 1 << 12, 1 << 10
+    out, events = _profiled(lambda: _served_cc(0.0, name="uf", n=n, w=w), tmp_path)
+    rng = np.random.default_rng(11)  # _served_cc's edges
+    src = rng.integers(0, cap, n).astype(np.int32)
+    dst = rng.integers(0, cap, n).astype(np.int32)
+    parent, seen = jnp.arange(cap, dtype=jnp.int32), np.zeros(cap, bool)
+    assert len(out) == n // w
+    for k, record in enumerate(out):
+        s, d = jnp.asarray(src[k * w : (k + 1) * w]), jnp.asarray(dst[k * w : (k + 1) * w])
+        parent, _ = rounds_union_edges(parent, s, d)
+        seen[src[k * w : (k + 1) * w]] = seen[dst[k * w : (k + 1) * w]] = True
+        want = [np.asarray(cap), np.asarray(parent), seen]
+        assert [(x.dtype, x.shape) for x in record] == [(x.dtype, x.shape) for x in want]
+        assert [x.tobytes() for x in record] == [x.tobytes() for x in want], k
+    tags = {
+        stats["window"]: stats
+        for name, _, _, stats in events
+        if name == "gelly.sink.copy" and stats.get("job") == "default/uf"
+    }
+    assert sorted(tags) == list(range(len(out)))
+    for stats in tags.values():
+        assert stats["uf_full_passes"] == int(stats["uf_hook_rounds"] > 0), stats
+    pipe = metrics.pipeline_stats()
+    assert pipe["uf_windows"] == len(out), pipe
+    for key in ("uf_hook_rounds", "uf_full_passes", "uf_root_steps"):
+        assert pipe[key] == sum(t[key] for t in tags.values()), (key, pipe)
+    assert 0 < pipe["uf_full_passes"] <= pipe["uf_windows"], pipe
+    assert pipe["uf_hook_rounds"] >= pipe["uf_full_passes"], pipe
+
+
 @pytest.mark.parametrize("query", ["cc", "degree"])
 def test_fold_and_combine_lower_under_stable_names(query):
     """The fold and combine executables carry stable names on every plane

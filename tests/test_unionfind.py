@@ -5,9 +5,12 @@ randomized equivalence checks of the batched kernel against a plain sequential
 union-find.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from fixtures import rounds_union_edges
 from gelly_streaming_tpu.ops import unionfind as uf
 from gelly_streaming_tpu.summaries.disjoint_set import DisjointSet
 
@@ -164,3 +167,171 @@ def test_parity_even_cycle_ok():
     p2 = uf.parity_union_edges(p2, src, dst)
     seen = jnp.zeros((c,), bool).at[jnp.array([1, 2, 3, 4])].set(True)
     assert bool(uf.is_bipartite(p2, seen))
+
+
+# ---- the window-local fold against the hook-and-compress rounds -------------
+
+
+def _benchmark_kronecker():
+    """The benchmark's own Graph 500 generator (benchmark/generators)."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).parents[1] / "benchmark/generators/kronecker.py"
+    spec = importlib.util.spec_from_file_location("bench_kronecker", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _random_windows(n=512, windows=6, m=256, seed=5):
+    rng = np.random.default_rng(seed)
+    return n, [
+        (rng.integers(0, n, m), rng.integers(0, n, m), None) for _ in range(windows)
+    ]
+
+
+def _path_window(n=512, seed=6):
+    # one window holding a path over shuffled ids: many hook rounds
+    ids = np.random.default_rng(seed).permutation(n)
+    return n, [(ids[:-1], ids[1:], None)]
+
+
+def _ascending_path_window(n=512):
+    # edges (k, k+1), as with ids numbered by arrival: one round hooks a
+    # chain n - 1 deep (k+1 -> k)
+    return n, [(np.arange(n - 1), np.arange(1, n), None)]
+
+
+def _descending_path_window(n=512):
+    # the same path listed from its far end, each edge (k+1, k)
+    return n, [(np.arange(n - 1, 0, -1), np.arange(n - 2, -1, -1), None)]
+
+
+def _star_window(n=256):
+    # the hub has the largest id: every leaf hooks it, then each other
+    leaves = np.arange(n - 1)
+    return n, [(np.full(n - 1, n - 1), leaves, None)]
+
+
+def _joined_window(n=256, seed=7):
+    rng = np.random.default_rng(seed)
+    w = (rng.integers(0, n, 200), rng.integers(0, n, 200), None)
+    return n, [w, w]  # the second fold finds every edge joined
+
+
+def _masked_windows(n=256, seed=8):
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(3):
+        mask = rng.random(128) < 0.5
+        out.append((rng.integers(0, n, 128), rng.integers(0, n, 128), mask))
+    return n, out
+
+
+def _kronecker_windows(scale=16, windows=4):
+    kr = _benchmark_kronecker()
+    params = {"SCALE": scale, "edgefactor": 16, "A": 0.57, "B": 0.19, "C": 0.19}
+    w = 1 << (scale - 2)  # the cell's window share: 2^21 edges at SCALE 23
+    return 1 << scale, [
+        (*kr.edges(params, 500, k * w, w), None) for k in range(windows)
+    ]
+
+
+_FOLD_CASES = {
+    "random": _random_windows,
+    "path": _path_window,
+    "path-ascending": _ascending_path_window,
+    "path-descending": _descending_path_window,
+    "star": _star_window,
+    "joined": _joined_window,
+    "masked": _masked_windows,
+    "kronecker-s16": _kronecker_windows,
+}
+
+
+def _root_steps_bound(n, rounds):
+    # a round resolves its hooked roots in 2 + ceil(log2(depth)) gathers
+    return rounds * (2 + int(np.ceil(np.log2(n))))
+
+
+@pytest.mark.parametrize("case", sorted(_FOLD_CASES))
+def test_window_fold_matches_rounds_reference(case):
+    """The summary fold (``union_edges_with_seen``, what
+    ``ConnectedComponents.update`` runs) gives the hook-and-compress
+    rounds' parent and ``seen`` bit for bit, in as many hook rounds, with
+    one whole-array pass when something hooked and none otherwise, and
+    resolves each round's roots in log-depth steps."""
+    n, windows = _FOLD_CASES[case]()
+    fold = jax.jit(uf.union_edges_with_seen)
+    reference = jax.jit(rounds_union_edges)
+    parent = ref_parent = uf.init_parent(n)
+    seen = ref_seen = jnp.zeros((n,), bool)
+    for k, (src, dst, mask) in enumerate(windows):
+        src = jnp.asarray(src, jnp.int32)
+        dst = jnp.asarray(dst, jnp.int32)
+        mask = None if mask is None else jnp.asarray(mask)
+        parent, seen, stats = fold(parent, seen, src, dst, mask)
+        ref_parent, ref_rounds = reference(ref_parent, src, dst, mask)
+        ref_seen = ref_seen.at[src].max(True if mask is None else mask)
+        ref_seen = ref_seen.at[dst].max(True if mask is None else mask)
+        np.testing.assert_array_equal(np.asarray(parent), np.asarray(ref_parent), f"window {k}")
+        np.testing.assert_array_equal(np.asarray(seen), np.asarray(ref_seen), f"window {k}")
+        counts = dict(zip(uf.UF_STATS, np.asarray(stats).tolist()))
+        assert counts["hook_rounds"] == int(ref_rounds), (k, counts)
+        assert counts["full_passes"] == int(counts["hook_rounds"] > 0), (k, counts)
+        assert (counts["root_steps"] > 0) == (counts["hook_rounds"] > 0), (k, counts)
+        assert counts["root_steps"] <= _root_steps_bound(n, counts["hook_rounds"]), (k, counts)
+    if case == "joined":
+        assert counts == {"hook_rounds": 0, "full_passes": 0, "root_steps": 0}
+    if case in ("path", "star"):
+        assert counts["hook_rounds"] >= 2, counts
+    if case.startswith("path-"):
+        # one round hooks the whole chain; a chase would take n gathers
+        assert counts["hook_rounds"] == 1, counts
+        assert counts["root_steps"] <= 2 * np.log2(n) + 2, counts
+
+
+def _pair_partitions(n):
+    # a: {2k, 2k+1}; the edges (v, b[v]) of b: {2k+1, 2k+2}, as
+    # ``merge_parents(a, b)`` folds them: one round hooks a chain n/2 deep
+    v = np.arange(n)
+    return v - v % 2, v, np.where(v % 2 == 0, np.maximum(v - 1, 0), v)
+
+
+@pytest.mark.parametrize(
+    "kind", ["chain", "pointers-up", "random-forest", "pair-partitions"]
+)
+def test_union_edges_on_uncompressed_input_keeps_its_result(kind):
+    """``union_edges`` takes any parent-pointer array, compressed or not,
+    and returns what the hook-and-compress rounds return, resolving each
+    round's roots in log-depth steps."""
+    n = 256
+    rng = np.random.default_rng(9)
+    src = rng.integers(0, n, 64)
+    dst = rng.integers(0, n, 64)
+    if kind == "chain":
+        parent = np.maximum(np.arange(n) - 1, 0)  # depth n - 1
+    elif kind == "pointers-up":
+        parent = np.minimum(np.arange(n) + 1, n - 1)  # roots are not minima
+    elif kind == "random-forest":
+        parent = np.array([rng.integers(0, v + 1) for v in range(n)])
+    else:
+        parent, src, dst = _pair_partitions(n)
+    parent = jnp.asarray(parent, jnp.int32)
+    src = jnp.asarray(src, jnp.int32)
+    dst = jnp.asarray(dst, jnp.int32)
+    want, rounds = rounds_union_edges(parent, src, dst)
+    np.testing.assert_array_equal(
+        np.asarray(uf.union_edges(parent, src, dst)), np.asarray(want)
+    )
+    _, stats = uf.fold_roots(uf.compress(parent), src, dst)  # union_edges' fold
+    counts = dict(zip(uf.UF_STATS, np.asarray(stats).tolist()))
+    assert counts["hook_rounds"] == int(rounds), counts
+    assert counts["root_steps"] <= _root_steps_bound(n, counts["hook_rounds"]), counts
+    if kind == "pair-partitions":
+        assert counts["hook_rounds"] == 1, counts
+        assert counts["root_steps"] <= 2 * np.log2(n) + 2, counts
+        np.testing.assert_array_equal(
+            np.asarray(uf.merge_parents(parent, dst)), np.zeros(n, np.int32)
+        )

@@ -100,12 +100,12 @@ def test_unpack_fuses_into_union_fold():
 
     def fold(parent, seen, buf):
         s, d = wire.unpack_edges(buf, batch, 2)
-        return uf.union_edges_with_seen(parent, seen, s, d, None)
+        return uf.union_edges_with_seen(parent, seen, s, d, None)[:2]
 
     parent = uf.init_parent(capacity)
     seen = jnp.zeros((capacity,), bool)
     p1, s1 = jax.jit(fold)(parent, seen, jnp.asarray(wire.pack_edges(src, dst, 2)))
-    p2, s2 = uf.union_edges_with_seen(parent, seen, src, dst, None)
+    p2, s2, _ = uf.union_edges_with_seen(parent, seen, src, dst, None)
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
 
